@@ -23,32 +23,27 @@ sizes leave almost no dispatch cost to amortize (250-row partitions) —
 there the bars are 1.05x / 0.7x, a regression tripwire rather than a
 performance claim; scripts/verify.sh runs ``--smoke``.
 
-The mesh needs >= 4 devices, and ``XLA_FLAGS=--xla_force_host_platform_
-device_count=4`` only takes effect before jax is first imported — so
-when the current process has fewer devices, ``run()`` re-execs itself as
-a subprocess with the flag set and ``--emit-json``, then relays the
-rows.  CI's forced-multi-device leg runs the bench in-process.
+The mesh needs >= 4 devices and runs in this process on the devices
+JAX holds (a chip belongs to the one process that touched JAX first, so
+the bench never hands the mesh to a child).  With fewer devices it fails
+and says so.  A CPU rehearsal gets four host devices from its caller,
+which sets ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` before
+Python starts (``scripts/verify.sh`` and CI's mesh leg do).
 
 Usage: PYTHONPATH=src python -m benchmarks.mesh_bench [--smoke]
-                                                      [--emit-json PATH]
 """
 
 from __future__ import annotations
 
 import argparse
 import datetime as dt
-import json
-import os
-import subprocess
 import sys
-import tempfile
 
 from ._timing import stopwatch, timed as _timed
 
 FULL_USERS, FULL_MSGS, FULL_PARTS = 2000, 16000, 32
 SMOKE_USERS, SMOKE_MSGS, SMOKE_PARTS = 600, 2000, 8
 MESH_DEVICES = 4
-_FORCE_FLAG = f"--xla_force_host_platform_device_count={MESH_DEVICES}"
 
 
 def _canon(rows):
@@ -153,41 +148,26 @@ def _run_local(smoke: bool = False) -> list:
 
 
 def run(smoke: bool = False) -> list:
-    """Bench entry point for ``benchmarks.run``.  Re-execs with forced
-    host devices when this process can't host the mesh (XLA only honors
-    the flag before jax's first import)."""
+    """Bench entry point for ``benchmarks.run``: in-process, on the
+    devices this process holds."""
     import jax
-    if len(jax.devices()) >= MESH_DEVICES:
-        return _run_local(smoke)
-    with tempfile.NamedTemporaryFile("r", suffix=".json") as f:
-        cmd = [sys.executable, "-m", "benchmarks.mesh_bench",
-               "--emit-json", f.name] + (["--smoke"] if smoke else [])
-        env = dict(os.environ, XLA_FLAGS=_FORCE_FLAG)
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in ("src", env.get("PYTHONPATH", "")) if p)
-        proc = subprocess.run(cmd, env=env, capture_output=True, text=True)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"forced-multi-device subprocess failed:\n{proc.stdout}"
-                f"\n{proc.stderr}")
-        return json.load(f)["rows"]
+    have = len(jax.devices())
+    if have < MESH_DEVICES:
+        raise RuntimeError(
+            f"mesh bench needs {MESH_DEVICES} devices, JAX sees {have} "
+            f"({jax.devices()[0].platform}); on CPU, start Python with "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count="
+            f"{MESH_DEVICES}")
+    return _run_local(smoke)
 
 
 def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--smoke", action="store_true",
                    help="small dataset, fewer repeats (CI gate)")
-    p.add_argument("--emit-json", default="", metavar="PATH",
-                   help="write {'rows': [...]} to PATH (subprocess "
-                        "handshake; implies in-process execution)")
     args = p.parse_args()
     with stopwatch() as sw:
-        out = _run_local(smoke=args.smoke) if args.emit_json \
-            else run(smoke=args.smoke)
-    if args.emit_json:
-        with open(args.emit_json, "w") as f:
-            json.dump({"rows": out}, f, default=str)
-            f.write("\n")
+        out = run(smoke=args.smoke)
     print("name,us_mesh,us_loop,speedup,partitions,h2d_warm,retraces_warm")
     for r in out:
         print(f"{r['bench']},{r['us_per_call']:.1f},{r['us_loop']:.1f},"

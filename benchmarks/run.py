@@ -55,6 +55,8 @@ def main() -> None:
                    help="write a structured JSON report (bench rows + "
                         "obs metric snapshot) to PATH")
     args = p.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from . import (columnar_bench, feeds_bench, fuzzy_bench, index_bench,
                    ingest_bench, mesh_bench, residency_bench, serve_bench,

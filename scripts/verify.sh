@@ -50,7 +50,10 @@ export PYTHONHASHSEED=0
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q "$@"
 
 # Smoke-bench matrix: one invocation, one exit code (see run.py --smoke),
-# plus a structured JSON report CI keeps as an artifact.
+# plus a structured JSON report CI keeps as an artifact.  The mesh bench
+# runs in-process on four devices, which the CPU backend only offers
+# when XLA_FLAGS is set before Python starts.
+XLA_FLAGS=--xla_force_host_platform_device_count=4 \
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
     python -m benchmarks.run --smoke --json bench_smoke.json
 

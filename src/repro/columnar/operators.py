@@ -267,7 +267,8 @@ def aggregate_batch(batch: ColumnBatch, aggs: Dict[str, Tuple[str, str]],
             n = 0
             batch = batch.take(np.zeros(0, dtype=np.int64))
     arrays, meta = _kernel_agg_cols(batch, aggs)
-    res = K.fused_filter_aggregate(preds, arrays, n)
+    res = K.fused_filter_aggregate(
+        preds, arrays, n, summed=[m[1] in ("sum", "avg") for m in meta])
 
     def survivors() -> ColumnBatch:
         # non-vectorizable columns pay one mask gather, shared across them
@@ -301,8 +302,6 @@ def _finish_aggregate(aggs: Dict[str, Tuple[str, str]],
             s = res["sums"][i]
             mn = _decode_agg(res["mins"][i], kind, col)
             mx = _decode_agg(res["maxs"][i], kind, col)
-            if kind == "i64" and isinstance(s, float):
-                s = int(s)      # TPU f32 path returns floats
             _finish_agg(out, name, fn, partial, res["cnts"][i], s, mn, mx)
             continue
         # non-vectorizable column (obj / exotic combo): decoded python pass,

@@ -38,7 +38,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .. import obs
 from ..kernels import device_pool as _pool
@@ -380,7 +379,7 @@ def compile_chain(ds: Any, *, chain_ops: Tuple[str, ...], search_field: str,
             tuple((np.asarray(a, np.int64), np.asarray(b, np.int64),
                    np.asarray(off, np.int64)) for a, b, off in fb)
             for fb in bounds)
-        with enable_x64():
+        with jax.enable_x64(True):
             outs = _chain_core(dev_tiers, dev_bounds, dev_idx,
                                np.asarray(n_live, np.int64),
                                tuple(dev_preds), dev_aggs,

@@ -23,8 +23,7 @@ Because ``keys`` is sorted, a btree range probe is two binary searches
 plus ONE contiguous ``positions`` slice; rtree circle probes and keyword
 token probes are a searchsorted against a (deduplicated) probe-key array
 plus one vectorized segment gather.  Candidate *bitmaps* then come from a
-single scatter pass (``kernels.fuzzy_ops.t_occurrence_mask`` with
-threshold 1 — the same kernel the ngram T-occurrence path dispatches),
+single host bool scatter (``PartitionedDataset._positions_mask``),
 composed with the dataset's newest-wins live-row selection exactly the
 way ngram candidate masks are: stale old-version positions are simply
 never selected, so no per-(key, pk) tombstone maintenance is needed.
@@ -221,7 +220,11 @@ class FieldPostings:
             return cls._empty(spec, "obj", has)
         dom: Optional[str] = None
         for v in raw:
-            dom = unify_kinds(dom, infer_kind(v))
+            k = infer_kind(v)
+            # keys compare numerically across ints and floats: an f64
+            # key domain orders a mixed field (decoding is not involved)
+            dom = "f64" if dom is not None and {dom, k} <= {"i64", "f64"} \
+                else unify_kinds(dom, k)
         if dom in _NUMERIC_DOMAINS:
             data = np.asarray([encode_scalar(v, dom) for v in raw],
                               dtype=np.int64 if dom != "f64"

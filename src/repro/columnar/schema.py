@@ -76,15 +76,15 @@ def infer_kind(v: Any) -> str:
 
 
 def unify_kinds(a: Optional[str], b: Optional[str]) -> str:
-    """Least common kind of two observations."""
+    """Least common column kind of two observations.  Ints and floats
+    mixed in one open field unify to ``obj``: an f64 column would decode
+    the ints as floats, and the row engine returns them as ints."""
     if a is None:
         return b or "obj"
     if b is None:
         return a
     if a == b:
         return a
-    if {a, b} <= {"i64", "f64"}:
-        return "f64"
     return "obj"
 
 

@@ -18,8 +18,9 @@ builders this module uses).  The structure is a columnar CSR:
              T <= 0 fallback candidate set)
 
 Candidate generation is T-occurrence: a query's gram-hit posting
-segments concatenate into one position array and a single fused count
-kernel (``kernels/fuzzy_ops.t_occurrence_mask``) keeps positions with
+segments (``hit_segments``) are gathered from each tier's
+device-resident positions into one count
+(``kernels/fuzzy_ops.ngram_t_occurrence``), which keeps positions with
 >= T hits.  The thresholds are the classic lower bounds, adjusted for
 hashing so collisions can only add false positives (verification removes
 them), never false negatives:
@@ -72,6 +73,27 @@ def value_gram_hashes(s: str, k: int) -> np.ndarray:
     """Sorted distinct gram hashes of one string (set semantics: the
     T-occurrence bounds above are stated over distinct grams)."""
     return np.unique(fnv1a_hash(gram_tokens(s, k)))
+
+
+def values_gram_hashes(values: Sequence[str], k: int
+                       ) -> Tuple[np.ndarray, np.ndarray]:
+    """``value_gram_hashes`` of many strings at once, as CSR: value v's
+    sorted distinct hashes are ``flat[offs[v]:offs[v + 1]]``.  One hash
+    pass over every gram and one lexsort dedupe replace the per-value
+    calls (dictionary columns of unique strings hash in bulk)."""
+    toks = [gram_tokens(v, k) for v in values]
+    counts = np.fromiter(map(len, toks), np.int64, count=len(toks))
+    offs = np.zeros(len(toks) + 1, dtype=np.int64)
+    if not counts.sum():
+        return _EMPTY_U64, offs
+    h = fnv1a_hash([t for ts in toks for t in ts])
+    vid = np.repeat(np.arange(len(toks), dtype=np.int64), counts)
+    order = np.lexsort((h, vid))
+    h, vid = h[order], vid[order]
+    keep = np.ones(h.shape[0], dtype=bool)
+    keep[1:] = (h[1:] != h[:-1]) | (vid[1:] != vid[:-1])
+    np.cumsum(np.bincount(vid[keep], minlength=len(toks)), out=offs[1:])
+    return h[keep], offs
 
 
 # CSR segment expansion and assembly are shared with the generalized
@@ -159,14 +181,8 @@ class GramPostings:
             return cls.from_values(
                 [v if isinstance(v, str) else None for v in col.decode()],
                 k)
-        n = len(col)
-        vals = col.values or []
-        per_val = [value_gram_hashes(v, k) for v in vals]
-        vcounts = np.fromiter((h.shape[0] for h in per_val), np.int64,
-                              count=len(vals))
-        voffs = np.zeros(len(vals) + 1, dtype=np.int64)
-        np.cumsum(vcounts, out=voffs[1:])
-        flat = np.concatenate(per_val) if per_val else _EMPTY_U64
+        flat, voffs = values_gram_hashes(col.values or [], k)
+        vcounts = np.diff(voffs)
         has = col.valid.copy()
         pos = np.nonzero(col.valid)[0].astype(np.int64)
         if pos.shape[0] == 0:
@@ -187,20 +203,26 @@ class GramPostings:
             return cls._empty(k, np.zeros(n_rows, dtype=bool))
         return cls.from_column(col, k)
 
+    def hit_segments(self, query_hashes: np.ndarray
+                     ) -> Tuple[np.ndarray, np.ndarray]:
+        """``[start, end)`` slices of ``positions`` holding the posting
+        segments of the query grams present in this component (ascending
+        and disjoint: ``query_hashes`` is sorted and distinct)."""
+        if self.grams.shape[0] == 0 or query_hashes.shape[0] == 0:
+            return _EMPTY_I64, _EMPTY_I64
+        lo = np.searchsorted(self.grams, query_hashes, side="left")
+        hi = np.searchsorted(self.grams, query_hashes, side="right")
+        found = lo[hi > lo]
+        return self.offsets[found], self.offsets[found + 1]
+
     def hit_positions(self, query_hashes: np.ndarray) -> np.ndarray:
         """Concatenated posting segments of the query grams present in
         this component: one int64 position per (query gram, row) hit,
         assembled by vectorized segment gathering (no python lists)."""
-        if self.grams.shape[0] == 0 or query_hashes.shape[0] == 0:
+        starts, ends = self.hit_segments(query_hashes)
+        if starts.shape[0] == 0:
             return _EMPTY_I64
-        lo = np.searchsorted(self.grams, query_hashes, side="left")
-        hi = np.searchsorted(self.grams, query_hashes, side="right")
-        found = hi > lo
-        if not found.any():
-            return _EMPTY_I64
-        starts = self.offsets[lo[found]]
-        counts = self.offsets[lo[found] + 1] - starts
-        return _segment_gather(self.positions, starts, counts)
+        return _segment_gather(self.positions, starts, ends - starts)
 
 
 def query_grams(spec: FuzzySpec, index_k: int) -> Tuple[np.ndarray, int]:

@@ -79,7 +79,15 @@ def verify_mask(batch: Any, mask: np.ndarray, spec: FuzzySpec, k: int
         cpos = pos[valid]
         codes = col.data[cpos].astype(np.int64)
         used = np.unique(codes)
-        ok_used = verify_values([vals[c] for c in used.tolist()], spec, k)
+        if spec[1] == "ed" and used.shape[0] > F.HOST_ED_MAX:
+            # many candidates: one pass over the column's resident
+            # dictionary (only the query's characters ship)
+            d = int(spec[3])
+            dist = F.dictionary_edit_distances(col, vals, spec[2], d)
+            ok_used = dist[used] <= d
+        else:
+            ok_used = verify_values([vals[c] for c in used.tolist()],
+                                    spec, k)
         lut = np.zeros(max(len(vals), 1), dtype=bool)
         lut[used] = ok_used
         out[cpos[lut[codes]]] = True

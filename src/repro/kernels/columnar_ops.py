@@ -18,14 +18,20 @@ backend-dispatch idiom:
                                  inputs (the LSM merge path: every column
                                  of the merged component is one gather)
 
-On TPU these run as compiled Pallas kernels: predicate columns are stacked
-into one [K, N] f32 operand, reductions accumulate across the row-block
-grid in VMEM (f32 — documented precision caveat for int64-domain columns).
-Elsewhere the pure-jnp oracle runs under ``jax.experimental.enable_x64``
-so int64 epoch-microsecond and dictionary-code columns evaluate exactly.
+Every operator has one exact answer and two device implementations,
+chosen by what the input holds: the jitted jnp core runs in the column's
+native dtype under x64 (exact for int64 epoch-microsecond, dictionary-
+code and float64 columns; XLA compiles it for every backend), and on TPU
+a Pallas kernel runs instead when every operand is exact in 32-bit
+integer lanes — integer columns whose values (memoized per immutable
+array) and bounds fit int32, and, where the caller reads a column's sum,
+an absolute sum that fits int32, so no partial sum can wrap.  Float columns always take the
+x64 core.  Both implementations read the same pooled, pow2-padded device
+operands (``kernels/device_pool``); the 32-bit narrowing happens on the
+device, so a warm query ships nothing either way.
 
-All jnp-oracle entry points pad their operands to the next power of two
-(invalid rows, so results are unchanged) before hitting the jitted cores:
+All entry points pad their operands to the next power of two (invalid
+rows, so results are unchanged) before hitting the jitted cores:
 repeated scans/merges over growing or gathered batches land on a bounded
 set of traced shapes instead of retracing per length.  ``trace_count()``
 exposes the cumulative number of traces so callers (ExecStats) can assert
@@ -41,8 +47,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ops import use_pallas
 from . import device_pool as _pool
@@ -67,20 +73,50 @@ _TRACES = {"n": 0}
 def trace_count() -> int:
     return _TRACES["n"]
 
+
+def _traced() -> None:
+    _TRACES["n"] += 1
+    _record_retrace()
+
 # (data [N], valid [N] bool, lo, hi) — already in the column's physical
 # (numeric) domain; None bound means unbounded on that side.
 Pred = Tuple[np.ndarray, np.ndarray, Any, Any]
 
-_BIG = 3.0e38   # f32-safe infinity stand-in for min/max identities
+LANES = 128            # Pallas operands are [rows, 128] lane-dense tiles
+_ROW_BLOCK = 256       # rows per grid step (256 x 128 int32 = 128 KiB)
+SMEM_CAP = 4096        # scalar-loop operands (candidate keys, postings
+                       # positions) held whole in SMEM; 1 MiB on v5e
+_I32 = np.iinfo(np.int32)
 
 
-def _bounds(lo: Any, hi: Any) -> Tuple[float, float]:
-    return (-np.inf if lo is None else lo, np.inf if hi is None else hi)
+def _row_grid(np2: int) -> Tuple[int, int]:
+    """(rows, rows per block) of a length-``np2`` operand viewed as
+    [rows, 128]; ``np2`` is a power of two >= 128, so the block divides
+    the rows and is either a multiple of 8 or the whole array."""
+    rows = np2 // LANES
+    return rows, min(rows, _ROW_BLOCK)
+
+
+def _lane_block(rb: int) -> Any:
+    return pl.BlockSpec((rb, LANES), lambda i: (i, 0))
+
+
+def _smem() -> Any:
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def pallas32(kernel: Any, *args: Any, **call: Any) -> Any:
+    """``pl.pallas_call(kernel, **call)(*args)`` traced with x64 off: the
+    callers run under x64 (their pooled operands are int64), but Mosaic
+    needs 32-bit grid indices and loop counters.  Operands are narrowed
+    to 32 bits before the call."""
+    with jax.enable_x64(False):
+        return pl.pallas_call(kernel, **call)(*args)
 
 
 # ---------------------------------------------------------------------------
-# jnp oracle (exact: runs in the column's native dtype under x64; jitted so
-# one query costs one dispatch per partition, not one per column op)
+# jnp cores (exact: native dtype under x64; jitted so one query costs one
+# dispatch per partition, not one per column op)
 # ---------------------------------------------------------------------------
 
 def _prep_bounds(data: np.ndarray, lo: Any, hi: Any
@@ -97,8 +133,11 @@ def _prep_bounds(data: np.ndarray, lo: Any, hi: Any
 
 @jax.jit
 def _mask_core(datas, valids, los, his):
-    _TRACES["n"] += 1
-    _record_retrace()
+    _traced()
+    return _mask_expr(datas, valids, los, his)
+
+
+def _mask_expr(datas, valids, los, his):
     m = None
     for x, v, lo, hi in zip(datas, valids, los, his):
         mm = v & (x >= lo) & (x <= hi)
@@ -115,12 +154,8 @@ def _ident(dtype, is_min: bool):
 
 @jax.jit
 def _agg_core(datas, valids, los, his, agg_datas, agg_valids):
-    _TRACES["n"] += 1
-    _record_retrace()
-    if datas:
-        mask = _mask_core(datas, valids, los, his)
-    else:
-        mask = jnp.ones(agg_datas[0].shape, dtype=bool)
+    _traced()
+    mask = _mask_expr(datas, valids, los, his)
     total = jnp.sum(mask)
     per_col = []
     for x, v in zip(agg_datas, agg_valids):
@@ -162,215 +197,237 @@ def _live_pred(n: int, np2: int) -> Pred:
     the buffer pool the same arrays instead of re-allocating per query."""
     live = np.zeros(np2, dtype=bool)
     live[:n] = True
-    return (np.zeros(np2, dtype=np.float64), live, None, None)
+    return (np.zeros(np2, dtype=np.int64), live, None, None)
 
 
-def _mask_jnp(preds: Sequence[Pred], n: int) -> np.ndarray:
+def _padded_preds(preds: Sequence[Pred], n: int, np2: int) -> List[Pred]:
+    """Predicates padded to ``np2``; with none, the liveness predicate
+    (padding rows must never count)."""
+    if preds:
+        return [_pad_pred(p, np2) for p in preds]
+    return [_live_pred(n, np2)]
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels (TPU): per-column [rows, 128] int32 tiles narrowed on the
+# device from the pooled operands; bounds read from SMEM
+# ---------------------------------------------------------------------------
+
+def _i32_bounds(preds: Sequence[Pred], np2: int) -> Optional[np.ndarray]:
+    """The predicates' [lo0, hi0, lo1, hi1, ...] int32 bounds when the
+    Pallas kernel is exact for them, else None.  Bounds go through the
+    jnp core's own conversion (``_prep_bounds``), so both paths decide
+    every row identically; a bound past the int32 range clamps to it,
+    which is exact because every value is inside."""
+    if np2 % LANES:
+        return None
+    out: List[int] = []
+    for data, _valid, lo, hi in preds:
+        if not _pool.fits_int32(data):
+            return None
+        l, h = (int(b) for b in _prep_bounds(data, lo, hi))
+        if l > _I32.max or h < _I32.min:
+            return None                 # empty range: leave it to the core
+        out += [max(l, int(_I32.min)), min(h, int(_I32.max))]
+    return np.asarray(out, dtype=np.int32)
+
+
+def _tiles(arrs, rows: int):
+    return [a.astype(jnp.int32).reshape(rows, LANES) for a in arrs]
+
+
+def _mask_tile(b_ref, x_refs, v_refs):
+    m = None
+    for j, (x_ref, v_ref) in enumerate(zip(x_refs, v_refs)):
+        x = x_ref[...]
+        mm = (v_ref[...] != 0) & (x >= b_ref[2 * j]) & (x <= b_ref[2 * j + 1])
+        m = mm if m is None else (m & mm)
+    return m
+
+
+def _mask_kernel(b_ref, *refs, k):
+    o_ref = refs[2 * k]
+    o_ref[...] = _mask_tile(b_ref, refs[:k], refs[k:2 * k]).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _mask_pallas_core(datas, valids, bounds, interpret=False):
+    _traced()
+    k = len(datas)
+    rows, rb = _row_grid(datas[0].shape[0])
+    out = pallas32(
+        functools.partial(_mask_kernel, k=k),
+        bounds, *_tiles(datas, rows), *_tiles(valids, rows),
+        grid=(rows // rb,),
+        in_specs=[_smem()] + [_lane_block(rb)] * (2 * k),
+        out_specs=_lane_block(rb),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+        interpret=interpret)
+    return out.reshape(-1) != 0
+
+
+def _tile_reduce(fn, x):
+    """[1, 1] reduction of a tile, one axis at a time: Mosaic lowers a
+    reduction to a 0-d scalar through jnp, which widens to 64 bits when
+    the caller traces under x64."""
+    return fn(fn(x, axis=0, keepdims=True), axis=1, keepdims=True)
+
+
+def _tile_sum(x):
+    return _tile_reduce(jnp.sum, x)
+
+
+def _agg_kernel(b_ref, *refs, k, m):
+    """Grid-accumulated [8, 128] int32 block: row 0 sums, 1 mins, 2 maxs,
+    3 valid-survivor counts (lane j = aggregate column j), row 4 lane 0
+    the predicate survivor count.  Rows are assembled with lane-iota
+    selects (no scatter), and merged into the resident output block with
+    a row-iota select between add / min / max."""
+    x_refs, v_refs = refs[:k], refs[k:2 * k]
+    a_refs, av_refs = refs[2 * k:2 * k + m], refs[2 * k + m:2 * k + 2 * m]
+    o_ref = refs[2 * k + 2 * m]
+    mask = _mask_tile(b_ref, x_refs, v_refs)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    hi = jnp.int32(_I32.max)
+    lo = jnp.int32(_I32.min)
+    sums = jnp.zeros((1, LANES), jnp.int32)
+    mins = jnp.full((1, LANES), hi, jnp.int32)
+    maxs = jnp.full((1, LANES), lo, jnp.int32)
+    cnts = jnp.zeros((1, LANES), jnp.int32)
+    for j in range(m):
+        a = a_refs[j][...]
+        ok = mask & (av_refs[j][...] != 0)
+        at = lane == j
+        sums = jnp.where(at, _tile_sum(jnp.where(ok, a, 0)), sums)
+        mins = jnp.where(at, _tile_reduce(jnp.min, jnp.where(ok, a, hi)),
+                         mins)
+        maxs = jnp.where(at, _tile_reduce(jnp.max, jnp.where(ok, a, lo)),
+                         maxs)
+        cnts = jnp.where(at, _tile_sum(ok.astype(jnp.int32)), cnts)
+    total = jnp.where(lane == 0, _tile_sum(mask.astype(jnp.int32)), 0)
+    pad = jnp.zeros((3, LANES), jnp.int32)
+    upd = jnp.concatenate([sums, mins, maxs, cnts, total, pad], axis=0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 0)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        o_ref[...] = jnp.where(row == 1, hi, jnp.where(row == 2, lo, 0))
+
+    s = o_ref[...]
+    o_ref[...] = jnp.where(row == 1, jnp.minimum(s, upd),
+                           jnp.where(row == 2, jnp.maximum(s, upd), s + upd))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _agg_pallas_core(datas, valids, bounds, agg_datas, agg_valids,
+                     interpret=False):
+    _traced()
+    k, m = len(datas), len(agg_datas)
+    rows, rb = _row_grid(datas[0].shape[0])
+    return pallas32(
+        functools.partial(_agg_kernel, k=k, m=m),
+        bounds, *_tiles(datas, rows), *_tiles(valids, rows),
+        *_tiles(agg_datas, rows), *_tiles(agg_valids, rows),
+        grid=(rows // rb,),
+        in_specs=[_smem()] + [_lane_block(rb)] * (2 * k + 2 * m),
+        out_specs=pl.BlockSpec((8, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((8, LANES), jnp.int32),
+        interpret=interpret)
+
+
+def _agg_i32_exact(aggs: Sequence[Tuple[np.ndarray, np.ndarray]],
+                   summed: Sequence[bool]) -> bool:
+    """Every aggregate column fits int32 lanes (count/min/max are then
+    exact); a column whose sum is read must also have an absolute sum (a
+    bound on every partial sum) that fits, so no partial sum can wrap."""
+    return len(aggs) <= LANES and all(
+        _pool.fits_int32(a) and (not s or _pool.abs_sum(a) <= _I32.max)
+        for (a, _v), s in zip(aggs, summed))
+
+
+# ---------------------------------------------------------------------------
+# range mask / fused aggregate dispatch
+# ---------------------------------------------------------------------------
+
+def _mask_dispatch(preds: Sequence[Pred], n: int, pallas: bool,
+                   interpret: bool) -> np.ndarray:
     """Operand arrays may already carry a pow2-padded tail of invalid
     rows (``columnar.batch.Column.padded``); anything shorter is padded
-    here so the jitted core only ever sees pow2 shapes."""
-    np2 = max(_pow2_len(n),
-              max(int(p[0].shape[0]) for p in preds))
-    preds = [_pad_pred(p, np2) for p in preds]
+    here so the jitted cores only ever see pow2 shapes."""
+    np2 = max(_pow2_len(n), max(int(p[0].shape[0]) for p in preds))
+    preds = _padded_preds(preds, n, np2)
     datas, valids, los, his = _split_preds(preds)
+    bounds = _i32_bounds(preds, np2) if pallas else None
     # already-resident operands (pooled component views) ship nothing;
     # only this call's actual uploads count as h2d
     k = len(datas)
     ops, missed = _pool.fetch(list(datas) + list(valids))
-    with enable_x64():
-        out = np.asarray(_mask_core(tuple(ops[:k]), tuple(ops[k:]),
-                                    los, his))
-    _record_dispatch("range_mask", h2d=missed, d2h=[out])
+    with jax.enable_x64(True):
+        if bounds is not None:
+            out = _mask_pallas_core(tuple(ops[:k]), tuple(ops[k:]), bounds,
+                                    interpret=interpret)
+        else:
+            out = _mask_core(tuple(ops[:k]), tuple(ops[k:]), los, his)
+        out = np.asarray(out)
+    _record_dispatch("range_mask", h2d=missed, d2h=[out],
+                     pallas=bounds is not None)
     return out[:n]
 
 
-def _agg_jnp(preds: Sequence[Pred],
-             aggs: Sequence[Tuple[np.ndarray, np.ndarray]],
-             n: int) -> Dict[str, Any]:
-    with enable_x64():
-        if not aggs:
-            mask = _mask_jnp(preds, n) if preds else np.ones(n, dtype=bool)
-            return {"count": int(mask.sum()), "sums": [], "mins": [],
-                    "maxs": [], "cnts": []}
-        np2 = max([_pow2_len(n)] + [int(a[0].shape[0]) for a in aggs]
-                  + [int(p[0].shape[0]) for p in preds])
-        if preds:              # padded rows are invalid in every conjunct
-            preds = [_pad_pred(p, np2) for p in preds]
-        elif np2 != n:         # no predicate: mask out padding explicitly
-            preds = [_live_pred(n, np2)]
-        padded_aggs = []
-        for data, valid in aggs:
-            pad = np2 - data.shape[0]
-            if pad > 0:
-                data = np.concatenate(
-                    [data, np.zeros(pad, dtype=data.dtype)])
-                valid = np.concatenate(
-                    [valid, np.zeros(pad, dtype=bool)])
-            padded_aggs.append((data, valid))
-        datas, valids, los, his = _split_preds(preds)
-        k, m = len(datas), len(padded_aggs)
-        ops, missed = _pool.fetch(
-            list(datas) + list(valids)
-            + [a[0] for a in padded_aggs] + [a[1] for a in padded_aggs])
-        total, per_col = _agg_core(
-            tuple(ops[:k]), tuple(ops[k:2 * k]), los, his,
-            tuple(ops[2 * k:2 * k + m]), tuple(ops[2 * k + m:]))
-        _record_dispatch("fused_filter_aggregate", h2d=missed)
-        out: Dict[str, Any] = {"count": int(total), "sums": [], "mins": [],
-                               "maxs": [], "cnts": []}
-        for s, mn, mx, cnt in per_col:
-            c = int(cnt)
-            out["cnts"].append(c)
-            out["sums"].append(s.item())
-            out["mins"].append(mn.item() if c else None)
-            out["maxs"].append(mx.item() if c else None)
-        return out
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernels (TPU): stacked [K, N] operands, grid-accumulated output
-# ---------------------------------------------------------------------------
-
-def _mask_kernel(p_ref, lo_ref, hi_ref, o_ref):
-    p = p_ref[...]                                  # [K8, bn]
-    lo = lo_ref[:, 0:1]
-    hi = hi_ref[:, 0:1]
-    m = jnp.all((p >= lo) & (p <= hi), axis=0)      # [bn]
-    o_ref[...] = jnp.broadcast_to(m.astype(jnp.float32)[None, :],
-                                  o_ref.shape)
-
-
-def _agg_kernel(p_ref, lo_ref, hi_ref, a_ref, av_ref, o_ref):
-    i = pl.program_id(0)
-    p = p_ref[...]                                  # [K8, bn]
-    lo = lo_ref[:, 0:1]
-    hi = hi_ref[:, 0:1]
-    m = jnp.all((p >= lo) & (p <= hi), axis=0)      # [bn]
-    a = a_ref[...]                                  # [M8, bn]
-    ok = m[None, :] & (av_ref[...] > 0.5)           # [M8, bn]
-    okf = ok.astype(jnp.float32)
-    m8 = a.shape[0]
-    pad = 128 - m8
-
-    def row(v, fill):
-        return jnp.pad(v, (0, pad), constant_values=fill)[None, :]
-
-    sums = row(jnp.sum(a * okf, axis=1), 0.0)
-    mins = row(jnp.min(jnp.where(ok, a, _BIG), axis=1), _BIG)
-    maxs = row(jnp.max(jnp.where(ok, a, -_BIG), axis=1), -_BIG)
-    cnts = row(jnp.sum(okf, axis=1), 0.0)
-    total = jnp.full((1, 128), 0.0, jnp.float32) \
-        .at[0, 0].set(jnp.sum(m.astype(jnp.float32)))
-    pad_rows = jnp.zeros((o_ref.shape[0] - 5, 128), jnp.float32)
-    upd = jnp.concatenate([sums, mins, maxs, cnts, total, pad_rows], axis=0)
-
-    @pl.when(i == 0)
-    def _init():
-        ident = jnp.concatenate([
-            jnp.zeros((1, 128), jnp.float32),
-            jnp.full((1, 128), _BIG, jnp.float32),
-            jnp.full((1, 128), -_BIG, jnp.float32),
-            jnp.zeros((2, 128), jnp.float32),
-            pad_rows], axis=0)
-        o_ref[...] = ident
-
-    s = o_ref[...]
-    o_ref[...] = jnp.concatenate([
-        s[0:1] + upd[0:1],
-        jnp.minimum(s[1:2], upd[1:2]),
-        jnp.maximum(s[2:3], upd[2:3]),
-        s[3:4] + upd[3:4],
-        s[4:5] + upd[4:5],
-        s[5:]], axis=0)
-
-
-def _stack_preds(preds: Sequence[Pred], n: int, block_n: int
-                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
-    """[K8, Np] f32 value matrix + [K8, 128] lo/hi bound columns.  Row K is
-    the row-validity predicate (1 for real rows, 0 for padding), so padded
-    lanes never contribute."""
-    k8 = max(8, ((len(preds) + 1 + 7) // 8) * 8)
-    np_pad = ((n + block_n - 1) // block_n) * block_n if n else block_n
-    vals = np.zeros((k8, np_pad), dtype=np.float32)
-    lo = np.full((k8, 128), -_BIG, dtype=np.float32)
-    hi = np.full((k8, 128), _BIG, dtype=np.float32)
-    for j, (data, valid, l, h) in enumerate(preds):
-        l, h = _bounds(l, h)
-        x = data[:n].astype(np.float32)     # operands may be pow2-padded
-        x = np.where(valid[:n], x, _BIG)    # invalid fails the hi bound
-        vals[j, :n] = x
-        lo[j, :] = np.float32(max(l, -_BIG))
-        hi[j, :] = np.float32(min(h, _BIG - 1))
-    j = len(preds)
-    vals[j, :n] = 1.0                       # row-validity predicate
-    lo[j, :] = 0.5
-    hi[j, :] = 1.5
-    return vals, lo, hi, np_pad
-
-
-def _mask_pallas(preds: Sequence[Pred], n: int, *, block_n: int = 512,
-                 interpret: bool = False) -> np.ndarray:
-    vals, lo, hi, np_pad = _stack_preds(preds, n, block_n)
-    k8 = vals.shape[0]
-    out = pl.pallas_call(
-        _mask_kernel,
-        grid=(np_pad // block_n,),
-        in_specs=[
-            pl.BlockSpec((k8, block_n), lambda i: (0, i)),
-            pl.BlockSpec((k8, 128), lambda i: (0, 0)),
-            pl.BlockSpec((k8, 128), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((8, block_n), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((8, np_pad), jnp.float32),
-        interpret=interpret,
-    )(vals, lo, hi)
-    out = np.asarray(out)
-    _record_dispatch("range_mask", h2d=[vals, lo, hi], d2h=[out])
-    return out[0, :n] > 0.5
-
-
-def _agg_pallas(preds: Sequence[Pred],
-                aggs: Sequence[Tuple[np.ndarray, np.ndarray]], n: int,
-                *, block_n: int = 512,
-                interpret: bool = False) -> Dict[str, Any]:
-    vals, lo, hi, np_pad = _stack_preds(preds, n, block_n)
-    k8 = vals.shape[0]
-    m8 = max(8, ((len(aggs) + 7) // 8) * 8)
-    if m8 > 128:
-        raise ValueError("fused kernel supports at most 128 agg columns")
-    a = np.zeros((m8, np_pad), dtype=np.float32)
-    av = np.zeros((m8, np_pad), dtype=np.float32)
-    for j, (data, valid) in enumerate(aggs):
-        a[j, :n] = data[:n].astype(np.float32)
-        av[j, :n] = valid[:n].astype(np.float32)
-    out = pl.pallas_call(
-        _agg_kernel,
-        grid=(np_pad // block_n,),
-        in_specs=[
-            pl.BlockSpec((k8, block_n), lambda i: (0, i)),
-            pl.BlockSpec((k8, 128), lambda i: (0, 0)),
-            pl.BlockSpec((k8, 128), lambda i: (0, 0)),
-            pl.BlockSpec((m8, block_n), lambda i: (0, i)),
-            pl.BlockSpec((m8, block_n), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-        interpret=interpret,
-    )(vals, lo, hi, a, av)
-    out = np.asarray(out, dtype=np.float64)
-    _record_dispatch("fused_filter_aggregate",
-                     h2d=[vals, lo, hi, a, av], d2h=[out])
-    m = len(aggs)
-    cnts = [int(round(c)) for c in out[3, :m]]
-    return {
-        "count": int(round(out[4, 0])),
-        "sums": [float(s) for s in out[0, :m]],
-        "mins": [None if c == 0 else float(v)
-                 for c, v in zip(cnts, out[1, :m])],
-        "maxs": [None if c == 0 else float(v)
-                 for c, v in zip(cnts, out[2, :m])],
-        "cnts": cnts,
-    }
+def _agg_dispatch(preds: Sequence[Pred],
+                  aggs: Sequence[Tuple[np.ndarray, np.ndarray]],
+                  n: int, pallas: bool, interpret: bool,
+                  summed: Sequence[bool]) -> Dict[str, Any]:
+    if not aggs:
+        mask = _mask_dispatch(preds, n, pallas, interpret) if preds \
+            else np.ones(n, dtype=bool)
+        return {"count": int(mask.sum()), "sums": [], "mins": [],
+                "maxs": [], "cnts": []}
+    np2 = max([_pow2_len(n)] + [int(a[0].shape[0]) for a in aggs]
+              + [int(p[0].shape[0]) for p in preds])
+    # padded rows are invalid in every conjunct (or masked by liveness)
+    preds = _padded_preds(preds, n, np2)
+    padded_aggs = []
+    for data, valid in aggs:
+        pad = np2 - data.shape[0]
+        if pad > 0:
+            data = np.concatenate([data, np.zeros(pad, dtype=data.dtype)])
+            valid = np.concatenate([valid, np.zeros(pad, dtype=bool)])
+        padded_aggs.append((data, valid))
+    datas, valids, los, his = _split_preds(preds)
+    bounds = _i32_bounds(preds, np2) \
+        if pallas and _agg_i32_exact(padded_aggs, summed) else None
+    k, m = len(datas), len(padded_aggs)
+    ops, missed = _pool.fetch(
+        list(datas) + list(valids)
+        + [a[0] for a in padded_aggs] + [a[1] for a in padded_aggs])
+    pd, pv = tuple(ops[:k]), tuple(ops[k:2 * k])
+    ad, av = tuple(ops[2 * k:2 * k + m]), tuple(ops[2 * k + m:])
+    out: Dict[str, Any] = {"sums": [], "mins": [], "maxs": [], "cnts": []}
+    with jax.enable_x64(True):
+        if bounds is not None:
+            blk = np.asarray(_agg_pallas_core(pd, pv, bounds, ad, av,
+                                              interpret=interpret))
+            out["count"] = int(blk[4, 0])
+            # an unread sum may have wrapped in int32: never report it
+            per_col = [(int(blk[0, j]) if summed[j] else None,
+                        int(blk[1, j]), int(blk[2, j]), int(blk[3, j]))
+                       for j in range(m)]
+            d2h = [blk]
+        else:
+            total, per = _agg_core(pd, pv, los, his, ad, av)
+            out["count"] = int(total)
+            per_col = [(s.item(), mn.item(), mx.item(), int(cnt))
+                       for s, mn, mx, cnt in per]
+            d2h = []
+    _record_dispatch("fused_filter_aggregate", h2d=missed, d2h=d2h,
+                     pallas=bounds is not None)
+    for s, mn, mx, c in per_col:
+        out["cnts"].append(c)
+        out["sums"].append(s)
+        out["mins"].append(mn if c else None)
+        out["maxs"].append(mx if c else None)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -381,8 +438,7 @@ def _agg_pallas(preds: Sequence[Pred],
 def _intersect_core(keys, cands):
     """Sorted merge via binary search: for each candidate, its insertion
     point in ``keys``; a hit scatters into the position bitmap."""
-    _TRACES["n"] += 1
-    _record_retrace()
+    _traced()
     n = keys.shape[0]
     pos = jnp.searchsorted(keys, cands)
     posc = jnp.clip(pos, 0, n - 1)
@@ -418,8 +474,7 @@ def _intersect_rank_core(keys, cands):
     """Membership plus its exclusive-cumsum rank fused into one dispatch:
     the merge path consumes the device mask on-device instead of round-
     tripping it to host between the bitmap and the rank pass."""
-    _TRACES["n"] += 1
-    _record_retrace()
+    _traced()
     n = keys.shape[0]
     pos = jnp.searchsorted(keys, cands)
     posc = jnp.clip(pos, 0, n - 1)
@@ -430,12 +485,54 @@ def _intersect_rank_core(keys, cands):
     return mem, jnp.cumsum(memi) - memi
 
 
-def _intersect_jnp(keys: np.ndarray, cands: np.ndarray) -> np.ndarray:
+def _intersect_kernel(c_ref, k_ref, o_ref, *, m):
+    """Membership of a [rb, 128] key tile in the SMEM-resident candidate
+    set: the loop reads one candidate scalar per step and ORs a full
+    vector compare — no gather, no host round-trip.  Edge-padded keys
+    and candidates only repeat real values, so padding never flips a
+    real row."""
+    keys = k_ref[...]
+
+    def body(j, acc):
+        return jnp.maximum(acc, (keys == c_ref[j]).astype(jnp.int32))
+
+    o_ref[...] = jax.lax.fori_loop(0, m, body, jnp.zeros_like(keys))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _intersect_pallas_core(keys, cands, interpret=False):
+    _traced()
+    rows, rb = _row_grid(keys.shape[0])
+    out = pallas32(
+        functools.partial(_intersect_kernel, m=cands.shape[0]),
+        cands.astype(jnp.int32), keys.astype(jnp.int32).reshape(rows, LANES),
+        grid=(rows // rb,),
+        in_specs=[_smem(), _lane_block(rb)],
+        out_specs=_lane_block(rb),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+        interpret=interpret)
+    return out.reshape(-1) != 0
+
+
+def _intersect_on_pallas(keys: np.ndarray, cands: np.ndarray) -> bool:
+    kp, cp = _pow2_pad(keys), _pow2_pad(cands)
+    return kp.shape[0] % LANES == 0 and cp.shape[0] <= SMEM_CAP \
+        and _pool.fits_int32(kp) and _pool.fits_int32(cp)
+
+
+def _intersect_dispatch(keys: np.ndarray, cands: np.ndarray,
+                        run_pallas: bool, interpret: bool) -> np.ndarray:
     n = keys.shape[0]
-    ops, missed = _pool.fetch([_pow2_pad(keys), _pow2_pad(cands)])
-    with enable_x64():
-        mask = np.asarray(_intersect_core(ops[0], ops[1]))
-    _record_dispatch("sorted_intersect_mask", h2d=missed, d2h=[mask])
+    kp, cp = _pow2_pad(keys), _pow2_pad(cands)
+    ops, missed = _pool.fetch([kp, cp])
+    with jax.enable_x64(True):
+        if run_pallas:
+            mask = _intersect_pallas_core(ops[0], ops[1], interpret=interpret)
+        else:
+            mask = _intersect_core(ops[0], ops[1])
+        mask = np.asarray(mask)
+    _record_dispatch("sorted_intersect_mask", h2d=missed, d2h=[mask],
+                     pallas=run_pallas)
     return mask[:n]
 
 
@@ -445,62 +542,11 @@ def _intersect_rank_jnp(keys: np.ndarray, cands: np.ndarray
     (see ``_intersect_rank_core``)."""
     n = keys.shape[0]
     ops, missed = _pool.fetch([_pow2_pad(keys), _pow2_pad(cands)])
-    with enable_x64():
+    with jax.enable_x64(True):
         mem_d, rank_d = _intersect_rank_core(ops[0], ops[1])
         mem, rank = np.asarray(mem_d), np.asarray(rank_d)
     _record_dispatch("sorted_intersect_mask", h2d=missed, d2h=[mem, rank])
     return mem[:n], rank[:n]
-
-
-def _intersect_kernel(k_ref, c_ref, o_ref, *, m):
-    """Membership of a key block in the (VMEM-resident) candidate set.
-    The rolled loop reads one candidate scalar per step and ORs a full
-    vector compare — no gather, no host round-trip; the bitmap comes out
-    fused with the row-validity flag so padded lanes never match."""
-    k = k_ref[...]                               # [8, bn]
-    keys = k[0:1, :]
-    live = k[1:2, :]
-
-    def body(j, acc):
-        c = c_ref[0, j]
-        return jnp.maximum(acc, (keys == c).astype(jnp.float32))
-
-    acc = jax.lax.fori_loop(0, m, body, jnp.zeros_like(keys))
-    o_ref[...] = jnp.broadcast_to(acc * live, o_ref.shape)
-
-
-def _intersect_pallas(keys: np.ndarray, cands: np.ndarray, n: int,
-                      *, block_n: int = 512,
-                      interpret: bool = False) -> np.ndarray:
-    m = int(cands.shape[0])
-    np_pad = ((n + block_n - 1) // block_n) * block_n
-    vals = np.zeros((8, np_pad), dtype=np.float32)
-    vals[0, :n] = keys.astype(np.float32)
-    vals[1, :n] = 1.0                            # row-validity flag
-    mp = max(128, ((m + 127) // 128) * 128)
-    cv = np.zeros((8, mp), dtype=np.float32)
-    cv[0, :m] = cands.astype(np.float32)
-    out = pl.pallas_call(
-        functools.partial(_intersect_kernel, m=m),
-        grid=(np_pad // block_n,),
-        in_specs=[
-            pl.BlockSpec((8, block_n), lambda i: (0, i)),
-            pl.BlockSpec((8, mp), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((8, block_n), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((8, np_pad), jnp.float32),
-        interpret=interpret,
-    )(vals, cv)
-    out = np.asarray(out)
-    _record_dispatch("sorted_intersect_mask", h2d=[vals, cv], d2h=[out])
-    return out[0, :n] > 0.5
-
-
-def _f32_exact_ints(arr: np.ndarray) -> bool:
-    """f32 compares keys exactly only below 2**24; larger pks (or float
-    pks) stay on the exact x64 oracle."""
-    return np.issubdtype(arr.dtype, np.integer) \
-        and bool((np.abs(arr) < 2 ** 24).all())
 
 
 # ---------------------------------------------------------------------------
@@ -510,36 +556,40 @@ def _f32_exact_ints(arr: np.ndarray) -> bool:
 def range_mask(preds: Sequence[Pred], n: int,
                *, force_pallas: Optional[bool] = None,
                interpret: bool = False) -> np.ndarray:
-    """Conjunctive range mask over K predicate columns -> bool [n]."""
+    """Conjunctive range mask over K predicate columns -> bool [n].
+    ``force_pallas`` overrides the backend check only: the Pallas kernel
+    still runs only where it is exact."""
     if n == 0:
         return np.zeros(0, dtype=bool)
     if not preds:
         return np.ones(n, dtype=bool)
     pallas = use_pallas() if force_pallas is None else force_pallas
-    if pallas:
-        return _mask_pallas(preds, n, interpret=interpret)
-    return _mask_jnp(preds, n)
+    return _mask_dispatch(preds, n, pallas, interpret)
 
 
 def fused_filter_aggregate(preds: Sequence[Pred],
                            aggs: Sequence[Tuple[np.ndarray, np.ndarray]],
-                           n: int, *, force_pallas: Optional[bool] = None,
+                           n: int, *, summed: Optional[Sequence[bool]] = None,
+                           force_pallas: Optional[bool] = None,
                            interpret: bool = False) -> Dict[str, Any]:
     """Filter + reduce in one pass.
 
     Returns ``{"count", "sums", "mins", "maxs", "cnts"}`` where ``count``
     is the number of mask survivors and per-aggregate lists are aligned
     with ``aggs`` (``cnts`` = valid survivors per column; ``mins``/
-    ``maxs`` are None when that is 0).
+    ``maxs`` are None when that is 0).  Values are python scalars in the
+    column's own domain on either implementation.  ``summed`` flags the
+    columns whose sum the caller reads (default: all); only those need
+    an int32-exact sum for the Pallas kernel, and another column's sum
+    may come back None.
     """
     if n == 0:
         return {"count": 0, "sums": [0] * len(aggs),
                 "mins": [None] * len(aggs), "maxs": [None] * len(aggs),
                 "cnts": [0] * len(aggs)}
     pallas = use_pallas() if force_pallas is None else force_pallas
-    if pallas:
-        return _agg_pallas(preds, aggs, n, interpret=interpret)
-    return _agg_jnp(preds, aggs, n)
+    summed = [True] * len(aggs) if summed is None else list(summed)
+    return _agg_dispatch(preds, aggs, n, pallas, interpret, summed)
 
 
 def sorted_intersect_mask(keys: np.ndarray, cands: np.ndarray,
@@ -549,21 +599,20 @@ def sorted_intersect_mask(keys: np.ndarray, cands: np.ndarray,
     sorted live-pk array: ``mask[i] == (keys[i] in cands)``.
 
     Empty inputs short-circuit (no zero-length kernel launch).  On TPU the
-    Pallas membership kernel runs when both sides are f32-exact ints
-    (|pk| < 2**24); otherwise the jitted x64 searchsorted oracle keeps
-    int64 pks exact.
+    Pallas membership kernel runs when both sides are int32-exact and the
+    candidates fit SMEM (``SMEM_CAP``); otherwise the jitted x64
+    searchsorted core keeps int64 pks exact, with the host sorted merge
+    below the dispatch floor.
     """
     n, m = int(keys.shape[0]), int(cands.shape[0])
     if n == 0 or m == 0:
         return np.zeros(n, dtype=bool)
     pallas = use_pallas() if force_pallas is None else force_pallas
-    if pallas and (force_pallas
-                   or (_f32_exact_ints(keys) and _f32_exact_ints(cands))):
-        return _intersect_pallas(keys, cands, n, interpret=interpret)
-    if n + m <= 4096:
+    run_pallas = pallas and _intersect_on_pallas(keys, cands)
+    if not run_pallas and n + m <= 4096:
         # below the jax dispatch floor the host sorted merge wins outright
         return _sorted_merge_mask(keys, cands)
-    return _intersect_jnp(keys, cands)
+    return _intersect_dispatch(keys, cands, run_pallas, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -624,7 +673,7 @@ def sorted_merge_take(key_arrays: Sequence[np.ndarray],
     merge-includes-oldest collapse).
 
     Numeric keys reuse the ``sorted_intersect_mask`` dispatch stack
-    (Pallas membership kernel on TPU for f32-exact ints, the jitted
+    (Pallas membership kernel on TPU for int32-exact keys, the jitted
     pow2-padded x64 searchsorted oracle elsewhere, host merge below the
     dispatch floor): each component's membership bitmap over the sorted
     key union doubles as its position map via an exclusive cumsum —

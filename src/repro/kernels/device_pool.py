@@ -50,19 +50,20 @@ from typing import Any, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .. import obs
 from ..columnar.batch import pow2_len as _pow2_len
 
-__all__ = ["DevicePool", "pool", "fetch", "padded", "release_component",
-           "clear", "stats"]
+__all__ = ["DevicePool", "pool", "fetch", "padded", "memo", "fits_int32",
+           "abs_sum",
+           "release_component", "clear", "stats"]
 
 _HITS = obs.counter("buffer_pool.hits")
 _MISSES = obs.counter("buffer_pool.misses")
 _EVICTIONS = obs.counter("buffer_pool.evictions")
 _RESHARDS = obs.counter("buffer_pool.reshard_evictions")
 _RESIDENT = obs.gauge("buffer_pool.resident_bytes")
+_I32 = np.iinfo(np.int32)
 
 
 def _poolable(a: Any) -> bool:
@@ -79,8 +80,9 @@ class DevicePool:
                             Tuple[Any, Any, int, Any]] = {}
         # id(host) -> placements currently resident for that id
         self._by_id: Dict[int, set] = {}
-        # (id(host), fill) -> (weakref(host), padded host, finalizer)
-        self._pads: Dict[Tuple[int, str], Tuple[Any, np.ndarray, Any]] = {}
+        # (id(host), tag) -> (weakref(host), derived value, finalizer):
+        # padded views (tag "edge"/"zero") and per-array facts
+        self._pads: Dict[Tuple[int, str], Tuple[Any, Any, Any]] = {}
         self._resident = 0
 
     # -- residency ----------------------------------------------------------
@@ -89,7 +91,7 @@ class DevicePool:
             ) -> Tuple[Any, bool]:
         """Device copy of ``arr`` under ``placement`` (None: default
         device; a ``NamedSharding``: mesh-sharded) plus whether it was
-        already resident.  Uploads happen under ``enable_x64`` so
+        already resident.  Uploads happen under x64 so
         int64/float64 operands keep their width (matching the jnp-oracle
         kernel convention).  Uploading under a new placement evicts the
         array's copies under any other placement first (reshard
@@ -100,7 +102,7 @@ class DevicePool:
             if e is not None and e[0]() is arr:
                 _HITS.inc()
                 return e[1], True
-        with enable_x64():
+        with jax.enable_x64(True):
             if placement is None:
                 dev = jnp.asarray(arr)
             else:
@@ -159,26 +161,53 @@ class DevicePool:
         np2 = _pow2_len(n)
         if np2 == n and n > 0:
             return arr
-        key = (id(arr), fill)
+
+        def build(a: np.ndarray) -> np.ndarray:
+            if n == 0:
+                return np.zeros(max(np2, 1), dtype=a.dtype)
+            tail = a[-1] if fill == "edge" else 0
+            return np.concatenate([a, np.full(np2 - n, tail, dtype=a.dtype)])
+        return self.memo(arr, fill, build)
+
+    def fits_int32(self, arr: np.ndarray) -> bool:
+        """Whether every element of an integer (or bool) array lies in
+        the int32 range — the domain of the Pallas kernels, which compare
+        and reduce in 32-bit lanes.  Memoized by identity: component
+        columns are immutable, so one host pass per array suffices."""
+        def build(a: np.ndarray) -> bool:
+            if a.dtype.kind not in "biu":
+                return False
+            if a.size == 0 or a.dtype.kind == "b":
+                return True
+            return bool(int(a.min()) >= _I32.min and int(a.max()) <= _I32.max)
+        return self.memo(arr, "i32", build)
+
+    def abs_sum(self, arr: np.ndarray) -> int:
+        """Sum of absolute values of an integer array (exact python int),
+        memoized by identity: bounds every partial sum a kernel can form
+        over any subset of the array."""
+        return self.memo(arr, "abssum", lambda a: int(
+            np.abs(a.astype(np.int64)).sum(dtype=np.int64)))
+
+    def memo(self, arr: Any, tag: str, build: Any) -> Any:
+        """Value derived from ``arr`` (any weak-referenceable immutable
+        owner) by ``build``, kept while ``arr`` lives: identity-keyed and
+        weakref-guarded like the residency map, so a derived host array
+        is itself a stable pool key."""
+        key = (id(arr), tag)
         with self._lock:
             m = self._pads.get(key)
             if m is not None and m[0]() is arr:
                 return m[1]
-        if n == 0:
-            pad = np.zeros(max(np2, 1), dtype=arr.dtype)
-        elif fill == "edge":
-            pad = np.concatenate(
-                [arr, np.full(np2 - n, arr[-1], dtype=arr.dtype)])
-        else:
-            pad = np.concatenate([arr, np.zeros(np2 - n, dtype=arr.dtype)])
+        val = build(arr)
         with self._lock:
             m = self._pads.get(key)
             if m is not None and m[0]() is arr:
                 return m[1]
             fin = weakref.finalize(arr, self._on_dead_pad, key)
             fin.atexit = False
-            self._pads[key] = (weakref.ref(arr), pad, fin)
-        return pad
+            self._pads[key] = (weakref.ref(arr), val, fin)
+        return val
 
     # -- eviction -----------------------------------------------------------
 
@@ -242,6 +271,14 @@ class DevicePool:
     def entry_count(self) -> int:
         return len(self._entries)
 
+    def placed_device_counts(self) -> List[int]:
+        """Number of distinct devices holding each resident copy uploaded
+        under an explicit placement (mesh-sharded stacks): a partition-
+        sharded operand on an N-device mesh spans N devices."""
+        with self._lock:
+            return [len(e[1].devices()) for key, e in self._entries.items()
+                    if key[1] is not None]
+
     def stats(self) -> Dict[str, int]:
         return {"entries": len(self._entries),
                 "resident_bytes": self._resident,
@@ -299,6 +336,18 @@ def fetch(arrs: Sequence[Any], placement: Any = None
 
 def padded(arr: np.ndarray, fill: str = "edge") -> np.ndarray:
     return pool.padded(arr, fill)
+
+
+def memo(arr: Any, tag: str, build: Any) -> Any:
+    return pool.memo(arr, tag, build)
+
+
+def fits_int32(arr: np.ndarray) -> bool:
+    return pool.fits_int32(arr)
+
+
+def abs_sum(arr: np.ndarray) -> int:
+    return pool.abs_sum(arr)
 
 
 def release_component(comp: Any) -> None:

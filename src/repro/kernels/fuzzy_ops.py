@@ -1,16 +1,20 @@
 """Vectorized similarity kernels for the fuzzy query subsystem.
 
 Four entry points, numpy in / python out, following the
-``columnar_ops.py`` dispatch idiom (Pallas kernels on TPU, pow2-padded
-jitted-jnp cores under ``enable_x64`` elsewhere, host paths below the
-jax dispatch floor):
+``columnar_ops.py`` dispatch idiom (exact pow2-padded jitted-jnp cores
+under x64; on TPU the banded edit-distance DP runs as a Pallas kernel
+instead, over int32 char codes with the query in SMEM; host paths below
+the jax dispatch floor):
 
   fnv1a_hash(tokens)             vectorized FNV-1a-64 over a padded byte
                                  matrix — the one token/gram hash the
                                  ngram postings and MinHash share
-  t_occurrence_mask(pos, n, T)   fused segmented-count: gram-hit positions
-                                 -> bool bitmap of rows with >= T hits
-                                 (the ngram index candidate generator)
+  ngram_t_occurrence(tiers, ...) segmented count over device-resident
+                                 per-tier postings and the query's segment
+                                 bounds -> bool bitmap of rows with >= T
+                                 gram hits (the ngram index candidate
+                                 generator: nothing per query is shipped
+                                 but the bounds)
   edit_distances(strs, q, d)     batched banded (saturating) Levenshtein
                                  DP over padded char-code matrices ->
                                  min(ed, d+1) per candidate string
@@ -33,24 +37,24 @@ from typing import Any, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ops import use_pallas
 from . import device_pool as _pool
-from .columnar_ops import _TRACES
+from .columnar_ops import LANES, _smem, _traced, pallas32
 from ..obs import record_dispatch as _record_dispatch
-from ..obs import record_retrace as _record_retrace
 from ..columnar.batch import pow2_len as _pow2_len
 
-__all__ = ["fnv1a_hash", "t_occurrence_mask", "edit_distances",
+__all__ = ["fnv1a_hash", "ngram_t_occurrence",
+           "edit_distances", "dictionary_edit_distances",
            "set_intersect_counts", "set_intersect_counts_padded",
            "encode_bitsets", "bitset_intersect_counts",
            "jaccard_from_counts", "jaccard_sims"]
 
 _FNV_OFFSET = np.uint64(14695981039346656037)
 _FNV_PRIME = np.uint64(1099511628211)
-_BIG = 3.0e38      # f32-safe infinity stand-in (Pallas operand padding)
+HOST_ED_MAX = 32   # candidate batches up to this size run the host DP
 
 
 # ---------------------------------------------------------------------------
@@ -60,19 +64,22 @@ _BIG = 3.0e38      # f32-safe infinity stand-in (Pallas operand padding)
 def fnv1a_hash(tokens: Sequence[str]) -> np.ndarray:
     """64-bit FNV-1a of each token, bit-identical to the classic per-byte
     python loop (``data.dedup._token_hash`` before the mod): tokens are
-    laid out as one [T, Lmax] byte matrix and the hash state advances one
-    *column* (not one token) at a time, so the python-level work is
-    O(max token length), not O(total bytes)."""
+    laid out as one [T, Lmax] byte matrix (filled with one scatter from
+    their joined bytes) and the hash state advances one *column* (not one
+    token) at a time, so the python-level work is O(max token length),
+    not O(total bytes)."""
     n = len(tokens)
     if n == 0:
         return np.zeros(0, dtype=np.uint64)
     bs = [t.encode() for t in tokens]
-    lens = np.fromiter((len(b) for b in bs), dtype=np.int64, count=n)
-    lmax = int(lens.max()) if n else 0
+    lens = np.fromiter(map(len, bs), dtype=np.int64, count=n)
+    lmax = int(lens.max())
     mat = np.zeros((n, max(lmax, 1)), dtype=np.uint64)
-    for i, b in enumerate(bs):
-        if b:
-            mat[i, :len(b)] = np.frombuffer(b, dtype=np.uint8)
+    flat = np.frombuffer(b"".join(bs), dtype=np.uint8)
+    if flat.size:
+        rows = np.repeat(np.arange(n), lens)
+        starts = np.cumsum(lens) - lens
+        mat[rows, np.arange(flat.size) - np.repeat(starts, lens)] = flat
     h = np.full(n, _FNV_OFFSET, dtype=np.uint64)
     for j in range(lmax):
         live = j < lens
@@ -85,113 +92,68 @@ def fnv1a_hash(tokens: Sequence[str]) -> np.ndarray:
 # T-occurrence segmented count (ngram candidate generation)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def _tocc_core(pos, thr, np2):
-    """Scatter-count gram hits per row position; padding positions point
-    at the extra slot ``np2`` so they never count."""
-    _TRACES["n"] += 1
-    _record_retrace()
-    cnt = jnp.zeros(np2 + 1, dtype=jnp.int32).at[pos].add(1)
-    return cnt[:np2] >= thr
+@functools.partial(jax.jit, static_argnums=(6,))
+def _tocc_tier_core(cnt, pos, starts, cum, nhits, off, hits_p2):
+    """Add one storage tier's query-gram hits into the running count.
+    Hit ``k`` (of ``nhits``) lies in segment ``g`` — the last whose
+    exclusive length prefix ``cum[g]`` is <= k — at ``starts[g] + k -
+    cum[g]`` of the tier's resident positions; it scatter-adds at its row
+    ``+ off`` of the storage concat.  Lanes past ``nhits`` (pow2 padding)
+    land in the sentinel slot, the count's last."""
+    _traced()
+    k = jnp.arange(hits_p2, dtype=jnp.int64)
+    g = jnp.searchsorted(cum, k, side="right") - 1
+    live = k < nhits
+    idx = jnp.where(live, starts[g] + k - cum[g], 0)
+    sentinel = cnt.shape[0] - 1
+    return cnt.at[jnp.where(live, pos[idx] + off, sentinel)].add(1)
 
 
-def _tocc_jnp(positions: np.ndarray, n: int, threshold: int) -> np.ndarray:
-    np2 = _pow2_len(n)
-    m = int(positions.shape[0])
-    mp = _pow2_len(m)
-    pos = np.concatenate([positions.astype(np.int64),
-                          np.full(mp - m, np2, dtype=np.int64)])
-    ops, missed = _pool.fetch([pos])
-    with enable_x64():
-        mask = np.asarray(_tocc_core(ops[0],
-                                     jnp.asarray(threshold, jnp.int32), np2))
-    _record_dispatch("t_occurrence_mask", h2d=missed, d2h=[mask])
-    return mask[:n]
+@jax.jit
+def _tocc_finish(cnt, thr):
+    _traced()
+    return cnt[:-1] >= thr
 
 
-def _tocc_kernel(r_ref, p_ref, t_ref, o_ref, *, m):
-    """Rolled-loop count: one posting scalar per step, a full vector
-    compare-accumulate per row block (the ``_intersect_kernel`` idiom
-    with a sum instead of a max)."""
-    r = r_ref[...]                               # [8, bn]
-    rowid = r[0:1, :]
-    live = r[1:2, :]
+def ngram_t_occurrence(tiers: Sequence[Any], total: int,
+                       threshold: int) -> np.ndarray:
+    """Bool [total] over the storage concat: rows whose query-gram hit
+    count reaches ``threshold``.  ``tiers`` are ``(offset, positions,
+    padded_positions, starts, ends)`` per storage tier, ``starts/ends``
+    the query grams' posting segments (``GramPostings.hit_segments``).
 
-    def body(j, acc):
-        c = p_ref[0, j]
-        return acc + (rowid == c).astype(jnp.float32)
-
-    acc = jax.lax.fori_loop(0, m, body, jnp.zeros_like(rowid))
-    thr = t_ref[0, 0]
-    o_ref[...] = jnp.broadcast_to((acc >= thr).astype(jnp.float32) * live,
-                                  o_ref.shape)
-
-
-def _tocc_pallas(positions: np.ndarray, n: int, threshold: int,
-                 *, block_n: int = 512, interpret: bool = False
-                 ) -> np.ndarray:
-    # pow2-padded operand widths AND loop bound: the kernel recompiles
-    # per padded shape only, not per exact posting count / row count
-    # (padding positions are -1, which matches no row id)
-    m = int(positions.shape[0])
-    np_pad = max(block_n, _pow2_len(n))
-    vals = np.zeros((8, np_pad), dtype=np.float32)
-    vals[0, :n] = np.arange(n, dtype=np.float32)
-    vals[1, :n] = 1.0                            # row-validity flag
-    mp = max(128, _pow2_len(m))
-    pv = np.full((8, mp), -1.0, dtype=np.float32)    # -1 matches no row
-    pv[0, :m] = positions.astype(np.float32)
-    tv = np.full((8, 128), np.float32(threshold), dtype=np.float32)
-    out = pl.pallas_call(
-        functools.partial(_tocc_kernel, m=mp),
-        grid=(np_pad // block_n,),
-        in_specs=[
-            pl.BlockSpec((8, block_n), lambda i: (0, i)),
-            pl.BlockSpec((8, mp), lambda i: (0, 0)),
-            pl.BlockSpec((8, 128), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((8, block_n), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((8, np_pad), jnp.float32),
-        interpret=interpret,
-    )(vals, pv, tv)
-    out = np.asarray(out)
-    _record_dispatch("t_occurrence_mask", h2d=[vals, pv, tv], d2h=[out])
-    return out[0, :n] > 0.5
-
-
-def t_occurrence_mask(positions: np.ndarray, n: int, threshold: int,
-                      *, force_pallas: Optional[bool] = None,
-                      interpret: bool = False) -> np.ndarray:
-    """Bool [n]: rows whose gram-hit count reaches ``threshold``.
-
-    ``positions`` is the concatenation of the query grams' posting
-    segments (one entry per (gram, row) hit, rows deduped per gram), so
-    one fused count pass replaces the per-gram python candidate lists.
-    On TPU the Pallas compare-accumulate kernel runs (row ids are f32-
-    exact below 2**24); elsewhere the jitted scatter-add core counts
-    under x64, with a host bincount below the jax dispatch floor.
-    """
-    if n == 0:
-        return np.zeros(0, dtype=bool)
-    if threshold <= 0:
-        return np.ones(n, dtype=bool)
-    positions = np.asarray(positions, dtype=np.int64)
-    m = int(positions.shape[0])
-    if m == 0:
-        return np.zeros(n, dtype=bool)
-    pallas = use_pallas() if force_pallas is None else force_pallas
-    if pallas and (force_pallas or n < 2 ** 24):
-        return _tocc_pallas(positions, n, threshold, interpret=interpret)
-    if threshold == 1:
-        # membership, not counting (the secondary postings candidate
-        # bitmaps probe at T=1): a host bool scatter beats a jitted
-        # dispatch off-TPU at every size
-        mask = np.zeros(n, dtype=bool)
-        mask[positions] = True
-        return mask
-    if n + m <= 4096:
-        return np.bincount(positions, minlength=n) >= threshold
-    return _tocc_jnp(positions, n, threshold)
+    The device path ships nothing per query but the segment bounds (a
+    query constant, like range bounds): the padded positions arrays are
+    pool keys for their postings' lifetime.  Each tier gathers only its
+    hit segments (work O(hits), pow2-padded) into one device-resident
+    count, so programs are keyed by pow2 buckets, not by the tier list.
+    Below the jax dispatch floor the segments gather and count on the
+    host."""
+    hits = [int((en - st).sum()) for _o, _p, _pp, st, en in tiers]
+    if sum(hits) == 0:
+        return np.zeros(total, dtype=bool)
+    if total + sum(hits) <= 4096:
+        from ..columnar.postings import segment_gather
+        pos = np.concatenate([off + segment_gather(p, st, en - st)
+                              for off, p, _pp, st, en in tiers])
+        return np.bincount(pos, minlength=total) >= threshold
+    live = [(t, h) for t, h in zip(tiers, hits) if h]
+    ops, missed = _pool.fetch([t[2] for t, _h in live])
+    with jax.enable_x64(True):
+        cnt = jnp.zeros(_pow2_len(total) + 1, dtype=jnp.int32)
+        for ((off, _p, _pp, st, en), h), pos in zip(live, ops):
+            gp = _pow2_len(st.shape[0])
+            starts = np.zeros(gp, dtype=np.int64)
+            starts[:st.shape[0]] = st
+            # exclusive length prefix; padding segments start past every hit
+            cum = np.full(gp, np.iinfo(np.int64).max, dtype=np.int64)
+            cum[0] = 0
+            cum[1:st.shape[0]] = np.cumsum(en - st)[:-1]
+            cnt = _tocc_tier_core(cnt, pos, starts, cum, np.int64(h),
+                                  np.int64(off), _pow2_len(h))
+        mask = np.asarray(_tocc_finish(cnt, np.int32(threshold)))
+    _record_dispatch("ngram_t_occurrence", h2d=missed, d2h=[mask])
+    return mask[:total]
 
 
 # ---------------------------------------------------------------------------
@@ -223,8 +185,7 @@ def _ed_core(cand, lens, q, qlen, d):
     beyond the band can only produce answers > d, so clamping them keeps
     the <= d decision exact and the final value equal to min(ed, d+1).
     """
-    _TRACES["n"] += 1
-    _record_retrace()
+    _traced()
     B, L = cand.shape
     M = q.shape[0]
     cap = (d + 1).astype(jnp.int64)
@@ -258,84 +219,118 @@ def _char_matrix(strings: Sequence[str], width: int, rows: int
     return mat
 
 
-def _ed_jnp(strings: Sequence[str], query: str, d: int) -> np.ndarray:
-    B = len(strings)
-    lens = np.fromiter((len(s) for s in strings), np.int64, count=B)
-    bp = _pow2_len(B)
-    lp = _pow2_len(max(int(lens.max()) if B else 0, 1))
-    mp = _pow2_len(max(len(query), 1))
-    cand = _char_matrix(strings, lp, bp)
-    lpad = np.concatenate([lens, np.zeros(bp - B, dtype=np.int64)])
-    q = np.zeros(mp, dtype=np.int32)
+def _query_codes(query: str) -> np.ndarray:
+    """The query's code points, zero-padded to a pow2 length (a query
+    constant: it rides as a kernel argument, never through the pool)."""
+    q = np.zeros(_pow2_len(max(len(query), 1)), dtype=np.int32)
     if query:
         q[:len(query)] = np.fromiter(map(ord, query), dtype=np.int32,
                                      count=len(query))
-    ops, missed = _pool.fetch([cand, lpad, q])
-    with enable_x64():
-        out = np.asarray(_ed_core(
-            ops[0], ops[1], ops[2],
-            jnp.asarray(len(query), jnp.int64), jnp.asarray(d, jnp.int64)))
-    _record_dispatch("edit_distances", h2d=missed, d2h=[out])
-    return out[:B]
+    return q
 
 
-def _ed_kernel(c_ref, l_ref, q_ref, o_ref, *, m, cap):
-    cand = c_ref[...]                            # [bb, Lp]
-    lens = l_ref[...][:, 0:1]                    # [bb, 1]
-    bb, L = cand.shape
-    jrow = jax.lax.broadcasted_iota(jnp.float32, (bb, L + 1), 1)
-    dp = jnp.minimum(jrow, cap)
-    qlen = q_ref[1, 0]
+def _ed_operands(strings: Sequence[str], pallas: bool) -> tuple:
+    """(candidate char matrix, lengths) in the layout of the chosen core:
+    [B, L] rows for the jnp core, [L + 1, B] lanes (row 0 a sentinel) for
+    the Pallas kernel; batch and width pow2-padded."""
+    B = len(strings)
+    lens = np.fromiter((len(s) for s in strings), np.int64, count=B)
+    width = int(lens.max()) if B else 0
+    if not pallas:
+        bp = _pow2_len(B)
+        cand = _char_matrix(strings, _pow2_len(max(width, 1)), bp)
+        return cand, np.concatenate([lens, np.zeros(bp - B, np.int64)])
+    bp = max(LANES, _pow2_len(B))
+    rows = max(8, _pow2_len(width + 1))
+    cand = np.full((rows, bp), -1, dtype=np.int32)
+    cand[1:, :] = _char_matrix(strings, rows - 1, bp).T
+    lv = np.zeros((1, bp), dtype=np.int32)
+    lv[0, :B] = lens
+    return cand, lv
+
+
+def _ed_run(cand: np.ndarray, lens: np.ndarray, B: int, query: str, d: int,
+            pallas: bool, interpret: bool = False) -> np.ndarray:
+    q = _query_codes(query)
+    ops, missed = _pool.fetch([cand, lens])
+    with jax.enable_x64(True):
+        if pallas:
+            params = np.asarray([len(query), d + 1], dtype=np.int32)
+            out = _ed_pallas_core(ops[0], ops[1], q, params,
+                                  interpret=interpret)
+        else:
+            out = _ed_core(ops[0], ops[1], q,
+                           np.asarray(len(query), np.int64),
+                           np.asarray(d, np.int64))
+        out = np.asarray(out)
+    _record_dispatch("edit_distances", h2d=missed, d2h=[out], pallas=pallas)
+    return out[:B].astype(np.int64)
+
+
+def _ed_jnp(strings: Sequence[str], query: str, d: int) -> np.ndarray:
+    return _ed_run(*_ed_operands(strings, False), len(strings), query, d,
+                   False)
+
+
+def _shift_rows(x, s, fill):
+    """``x`` shifted ``s`` rows down along the sublane axis (row j holds
+    old row j - s; the first ``s`` rows take ``fill``)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.where(row >= s, pltpu.roll(x, s, 0), fill)
+
+
+def _ed_kernel(p_ref, q_ref, c_ref, l_ref, o_ref):
+    """The banded DP of ``_ed_core`` with the batch on lanes: a tile is
+    [Lp, 128] — DP column j on sublanes, 128 candidates on lanes — so
+    the within-row min-plus recurrence is a log-step cummin down the
+    sublanes (rolls), and the answer row is lane-dense.  Row 0 of
+    ``c_ref`` is a sentinel; row j >= 1 holds each candidate's char j-1.
+    ``p_ref`` holds (query length, cap = d + 1); ``q_ref`` the query."""
+    cand = c_ref[...]
+    rows = cand.shape[0]
+    jrow = jax.lax.broadcasted_iota(jnp.int32, cand.shape, 0)
+    cap = p_ref[1]
+    big = jnp.int32(1 << 30)
 
     def body(i, dp):
-        qc = q_ref[0, i]
-        sub = (cand != qc).astype(jnp.float32)
-        left = jnp.zeros((bb, 1), jnp.float32) + (i + 1).astype(jnp.float32)
-        m_ = jnp.concatenate(
-            [left, jnp.minimum(dp[:, 1:] + 1.0, dp[:, :-1] + sub)], axis=1)
-        t = _cummin_last(m_ - jrow, _BIG)
-        new = jnp.minimum(t + jrow, cap)
-        return jnp.where(i.astype(jnp.float32) < qlen, new, dp)
+        sub = (cand != q_ref[i]).astype(jnp.int32)
+        m_ = jnp.minimum(dp + 1, _shift_rows(dp, 1, big) + sub)
+        m_ = jnp.where(jrow == 0, i + 1, m_)
+        t = m_ - jrow
+        s = 1
+        while s < rows:
+            t = jnp.minimum(t, _shift_rows(t, s, big))
+            s *= 2
+        return jnp.minimum(t + jrow, cap)
 
-    dp = jax.lax.fori_loop(0, m, body, dp)
-    onehot = (jrow == jnp.minimum(lens, float(L))).astype(jnp.float32)
-    dist = jnp.sum(dp * onehot, axis=1)          # [bb]
-    o_ref[...] = jnp.broadcast_to(dist[None, :], o_ref.shape)
+    dp = jax.lax.fori_loop(0, p_ref[0], body, jnp.minimum(jrow, cap))
+    pick = jnp.minimum(l_ref[...], rows - 1)             # [1, 128]
+    o_ref[...] = jnp.sum(jnp.where(jrow == pick, dp, 0), axis=0,
+                         keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ed_pallas_core(cand, lens, q, params, interpret=False):
+    _traced()
+    rows, bp = cand.shape
+    col = pl.BlockSpec((rows, LANES), lambda i: (0, i))
+    lane = pl.BlockSpec((1, LANES), lambda i: (0, i))
+    return pallas32(
+        _ed_kernel, params, q, cand, lens,
+        grid=(bp // LANES,),
+        in_specs=[_smem(), _smem(), col, lane],
+        out_specs=lane,
+        out_shape=jax.ShapeDtypeStruct((1, bp), jnp.int32),
+        interpret=interpret)[0]
 
 
 def _ed_pallas(strings: Sequence[str], query: str, d: int,
-               *, block_b: int = 8, interpret: bool = False) -> np.ndarray:
-    # pow2-padded batch AND query-loop bound (the kernel's ``i < qlen``
-    # guard skips padded query rows), so distinct queries of similar
-    # length share one compilation instead of one per exact length
-    B = len(strings)
-    lens = np.fromiter((len(s) for s in strings), np.int64, count=B)
-    bp = max(block_b, _pow2_len(B))
-    lp = _pow2_len(max(int(lens.max()) if B else 0, 1))
-    mp = max(128, _pow2_len(max(len(query), 1)))
-    cand = _char_matrix(strings, lp, bp).astype(np.float32)
-    lv = np.zeros((bp, 128), dtype=np.float32)
-    lv[:B, 0] = lens.astype(np.float32)
-    qv = np.zeros((8, mp), dtype=np.float32)
-    if query:
-        qv[0, :len(query)] = np.fromiter(map(ord, query), dtype=np.float32,
-                                         count=len(query))
-    qv[1, :] = np.float32(len(query))
-    out = pl.pallas_call(
-        functools.partial(_ed_kernel, m=mp, cap=float(d + 1)),
-        grid=(bp // block_b,),
-        in_specs=[
-            pl.BlockSpec((block_b, lp), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, 128), lambda i: (i, 0)),
-            pl.BlockSpec((8, mp), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((8, block_b), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((8, bp), jnp.float32),
-        interpret=interpret,
-    )(cand, lv, qv)
-    out = np.asarray(out)
-    _record_dispatch("edit_distances", h2d=[cand, lv, qv], d2h=[out])
-    return out[0, :B].astype(np.int64)
+               *, interpret: bool = False) -> np.ndarray:
+    # pow2-padded batch, width AND query length, so distinct queries of
+    # similar length share one compilation; the loop runs to the true
+    # query length (an SMEM scalar)
+    return _ed_run(*_ed_operands(strings, True), len(strings), query, d,
+                   True, interpret)
 
 
 def edit_distances(strings: Sequence[str], query: str, d: int,
@@ -343,21 +338,38 @@ def edit_distances(strings: Sequence[str], query: str, d: int,
                    interpret: bool = False) -> np.ndarray:
     """``min(edit_distance(s, query), d+1)`` per candidate: saturated
     (banded) distances whose ``<= d`` decision is exact.  Char codes are
-    f32-exact on the Pallas path (max code point 0x10FFFF < 2**24);
-    a tiny batch runs the host DP outright (one jax dispatch costs more).
+    int32-exact on the Pallas path (max code point 0x10FFFF); a tiny
+    batch runs the host DP outright on every backend (one dispatch, and
+    shipping the batch, cost more).
     """
     B = len(strings)
     if B == 0:
         return np.zeros(0, dtype=np.int64)
     d = max(int(d), 0)
-    pallas = use_pallas() if force_pallas is None else force_pallas
-    if pallas:
-        return _ed_pallas(strings, query, d, interpret=interpret)
-    if B <= 32:
+    if B <= HOST_ED_MAX:
         from ..core.functions import edit_distance
         return np.asarray([min(edit_distance(s, query), d + 1)
                            for s in strings], dtype=np.int64)
+    pallas = use_pallas() if force_pallas is None else force_pallas
+    if pallas:
+        return _ed_pallas(strings, query, d, interpret=interpret)
     return _ed_jnp(strings, query, d)
+
+
+def dictionary_edit_distances(owner: Any, values: Sequence[str], query: str,
+                              d: int, *, force_pallas: Optional[bool] = None,
+                              interpret: bool = False) -> np.ndarray:
+    """``edit_distances`` over a whole immutable string dictionary
+    (``values``, owned by ``owner`` — a component column): the char
+    matrix is built once per owner and layout, so it is a stable pool key
+    and stays device-resident; a repeated query ships only its own
+    characters.  Verification of many candidates runs here rather than
+    over per-query candidate batches."""
+    d = max(int(d), 0)
+    pallas = use_pallas() if force_pallas is None else force_pallas
+    cand, lens = _pool.memo(owner, "ed_pallas" if pallas else "ed_jnp",
+                            lambda _o: _ed_operands(values, pallas))
+    return _ed_run(cand, lens, len(values), query, d, pallas, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -368,8 +380,7 @@ def edit_distances(strings: Sequence[str], query: str, d: int,
 def _inter_core(a, alens, b):
     """Per-pair |A ∩ B| via a vmapped binary search of each A element in
     the (sorted, sentinel-padded) B row."""
-    _TRACES["n"] += 1
-    _record_retrace()
+    _traced()
     s1 = a.shape[1]
 
     def row(ar, al, br):
@@ -383,50 +394,10 @@ def _inter_core(a, alens, b):
 
 def _inter_jnp(a_mat, alens, b_mat) -> np.ndarray:
     ops, missed = _pool.fetch([a_mat, alens, b_mat])
-    with enable_x64():
+    with jax.enable_x64(True):
         out = np.asarray(_inter_core(ops[0], ops[1], ops[2]))
     _record_dispatch("set_intersect_counts", h2d=missed, d2h=[out])
     return out
-
-
-def _inter_kernel(a_ref, l_ref, b_ref, o_ref, *, s1):
-    a = a_ref[...]                               # [bp, S1]
-    b = b_ref[...]                               # [bp, S2]
-    al = l_ref[...][:, 0]                        # [bp]
-    bp = a.shape[0]
-    acc = jnp.zeros((bp,), jnp.float32)
-    for j in range(s1):                          # static unroll over S1
-        hit = jnp.any(b == a[:, j:j + 1], axis=1) & (j < al)
-        acc = acc + hit.astype(jnp.float32)
-    o_ref[...] = jnp.broadcast_to(acc[None, :], o_ref.shape)
-
-
-def _inter_pallas(a_mat, alens, b_mat, *, block_p: int = 8,
-                  interpret: bool = False) -> np.ndarray:
-    P, s1 = a_mat.shape
-    s2 = b_mat.shape[1]
-    pp = max(block_p, _pow2_len(P))     # callers pow2-pad; keep it stable
-    av = np.zeros((pp, s1), dtype=np.float32)
-    av[:P] = a_mat.astype(np.float32)
-    bv = np.full((pp, s2), _BIG, dtype=np.float32)
-    bv[:P] = np.where(b_mat >= (1 << 24), _BIG, b_mat).astype(np.float32)
-    lv = np.zeros((pp, 128), dtype=np.float32)
-    lv[:P, 0] = alens.astype(np.float32)
-    out = pl.pallas_call(
-        functools.partial(_inter_kernel, s1=s1),
-        grid=(pp // block_p,),
-        in_specs=[
-            pl.BlockSpec((block_p, s1), lambda i: (i, 0)),
-            pl.BlockSpec((block_p, 128), lambda i: (i, 0)),
-            pl.BlockSpec((block_p, s2), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((8, block_p), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((8, pp), jnp.float32),
-        interpret=interpret,
-    )(av, lv, bv)
-    out = np.asarray(out)
-    _record_dispatch("set_intersect_counts", h2d=[av, lv, bv], d2h=[out])
-    return out[0, :P].astype(np.int64)
 
 
 _SENTINEL = np.int64(np.iinfo(np.int64).max)
@@ -446,9 +417,8 @@ def _pad_sets(sets: Sequence[np.ndarray], fill: np.int64
 
 
 def set_intersect_counts_padded(a_mat: np.ndarray, alens: np.ndarray,
-                                b_mat: np.ndarray, blens: np.ndarray,
-                                *, force_pallas: Optional[bool] = None,
-                                interpret: bool = False) -> np.ndarray:
+                                b_mat: np.ndarray, blens: np.ndarray
+                                ) -> np.ndarray:
     """Pre-padded variant of ``set_intersect_counts`` for callers that
     gather pair rows out of one shared record matrix (FuzzyJoin verify:
     pad each record once, then every candidate pair is a fancy-index —
@@ -466,12 +436,6 @@ def set_intersect_counts_padded(a_mat: np.ndarray, alens: np.ndarray,
         l2 = np.zeros(pp, dtype=np.int64)
         l2[:P] = alens
         a_mat, b_mat, alens = a2, b2, l2
-    pallas = use_pallas() if force_pallas is None else force_pallas
-    if pallas and (force_pallas
-                   or (int(np.max(alens)) == 0
-                       or (a_mat[a_mat != _SENTINEL].max(initial=0)
-                           < 2 ** 24))):
-        return _inter_pallas(a_mat, alens, b_mat, interpret=interpret)[:P]
     if P <= 16:
         return np.asarray(
             [len(np.intersect1d(a_mat[i][:alens[i]],
@@ -481,20 +445,20 @@ def set_intersect_counts_padded(a_mat: np.ndarray, alens: np.ndarray,
 
 
 def set_intersect_counts(a_sets: Sequence[np.ndarray],
-                         b_sets: Sequence[np.ndarray],
-                         **kw: Any) -> np.ndarray:
+                         b_sets: Sequence[np.ndarray]) -> np.ndarray:
     """``|a_sets[i] & b_sets[i]|`` per pair.  Each set is a sorted array
     of distinct dictionary codes; the b side pads with an int64 sentinel
     (stays sorted) and the a side is masked by its true length, so pads
-    never match.  Codes must be < 2**24 for the Pallas path (dictionary
-    sizes are), exact int64 on the jnp path."""
+    never match.  The jnp core is exact int64 on every backend: the
+    pairs are per-query data shipped with every call, so there is no
+    resident operand for a TPU kernel to reuse."""
     P = len(a_sets)
     if P == 0:
         return np.zeros(0, dtype=np.int64)
     a_mat, alens, _ = _pad_sets(a_sets, np.int64(0))
     b_mat, blens, _ = _pad_sets(b_sets, _SENTINEL)
     return set_intersect_counts_padded(a_mat[:P], alens[:P], b_mat[:P],
-                                       blens[:P], **kw)
+                                       blens[:P])
 
 
 @jax.jit
@@ -504,8 +468,7 @@ def _popcount_inter_core(bits, ai, bi):
     then AND + popcount row-reduce (XLA ``population_count`` vectorizes
     on every backend, TPU included, so this core needs no separate
     Pallas variant)."""
-    _TRACES["n"] += 1
-    _record_retrace()
+    _traced()
     return jnp.sum(jax.lax.population_count(bits[ai] & bits[bi]), axis=1)
 
 
@@ -568,12 +531,12 @@ def jaccard_from_counts(inter: np.ndarray, a_sizes: np.ndarray,
     return np.where(union > 0, inter / np.maximum(union, 1.0), 1.0)
 
 
-def jaccard_sims(a_sets: Sequence[np.ndarray], b_sets: Sequence[np.ndarray],
-                 **kw: Any) -> np.ndarray:
+def jaccard_sims(a_sets: Sequence[np.ndarray], b_sets: Sequence[np.ndarray]
+                 ) -> np.ndarray:
     """Exact float64 Jaccard similarity per pair of dictionary-coded
     sets (intersection counted by the kernel, the division done host-
     side so decisions match the python ``len(&)/len(|)`` oracle)."""
-    inter = set_intersect_counts(a_sets, b_sets, **kw)
+    inter = set_intersect_counts(a_sets, b_sets)
     al = np.fromiter((len(s) for s in a_sets), np.float64,
                      count=len(a_sets))
     bl = np.fromiter((len(s) for s in b_sets), np.float64,

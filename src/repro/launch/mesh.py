@@ -7,6 +7,7 @@ device state, so launchers can set XLA_FLAGS first.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "POD_SHAPE", "MULTI_POD_SHAPE"]
 
@@ -17,7 +18,4 @@ MULTI_POD_SHAPE = (2, 16, 16)       # 2 pods = 512 chips: (pod, data, model)
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTI_POD_SHAPE if multi_pod else POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    axis_type = getattr(jax.sharding, "AxisType", None)  # absent on jax 0.4.x
-    kwargs = ({"axis_types": (axis_type.Auto,) * len(axes)}
-              if axis_type is not None else {})
-    return jax.make_mesh(shape, axes, **kwargs)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -87,13 +87,16 @@ def _nbytes(arrs: Sequence[Any]) -> int:
 
 
 def record_dispatch(name: str, h2d: Sequence[Any] = (),
-                    d2h: Sequence[Any] = ()) -> None:
+                    d2h: Sequence[Any] = (), pallas: bool = False) -> None:
     """Account one device-bound kernel call: ``h2d`` are the operand
-    arrays shipped to the jitted/Pallas core (post-padding; 0-d bound
-    scalars are excluded by convention), ``d2h`` the result arrays
-    fetched back (padded shape, before host-side slicing).  Updates the
-    process-wide kernel counters and attributes onto the innermost open
-    span when tracing is enabled."""
+    arrays shipped to the jitted/Pallas core (post-padding; query
+    constants — range bounds, thresholds, the query's own characters or
+    gram segment bounds — ride as kernel arguments and are excluded by
+    convention), ``d2h`` the result arrays fetched back (padded shape,
+    before host-side slicing).  ``pallas`` marks a call that ran the
+    operator's Pallas kernel rather than its XLA-compiled jnp core.
+    Updates the process-wide kernel counters and attributes onto the
+    innermost open span when tracing is enabled."""
     hb = _nbytes(h2d)
     db = _nbytes(d2h)
     _K_DISPATCH.inc(1)
@@ -107,6 +110,8 @@ def record_dispatch(name: str, h2d: Sequence[Any] = (),
                                    counter(f"kernel.{name}.h2d_bytes"),
                                    counter(f"kernel.{name}.d2h_bytes"))
     per[0].inc(1)
+    if pallas:
+        counter(f"kernel.{name}.pallas_dispatches").inc(1)
     if hb:
         per[1].inc(hb)
     if db:

@@ -18,12 +18,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 has explicit axis types; 0.4.x does not
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 __all__ = [
     "make_mesh", "make_host_mesh", "batch_axes", "mesh_axis_size",
@@ -45,11 +40,9 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
     if need > have:
         raise ValueError(
             f"mesh {tuple(shape)} needs {need} devices but only {have} are "
-            f"visible; launchers must set XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count before importing jax")
-    kwargs = ({"axis_types": (AxisType.Auto,) * len(axes)}
-              if AxisType is not None else {})
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+            f"visible")
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:

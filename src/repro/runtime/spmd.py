@@ -19,11 +19,11 @@ repartitioning lowers onto ``runtime/collectives.partition_by``
 Activation is explicit and ambient: ``with use_partition_mesh(4):``
 (or ``run_query(..., mesh=...)``) turns the SPMD paths on; with no
 active mesh every consumer keeps the 1-device python-loop fallback, and
-``tests/test_differential.py`` locks the two bit-for-bit.  Single-host
-multi-device comes from ``XLA_FLAGS=--xla_force_host_platform_device_
-count=N`` set before the first jax import (the CI mesh leg and
-``benchmarks/mesh_bench.py`` do this); nothing here touches jax device
-state at import time.
+``tests/test_differential.py`` locks the two bit-for-bit.  The mesh spans
+the devices this process holds: the chips of a TPU host, or for a CPU
+rehearsal host devices from ``XLA_FLAGS=--xla_force_host_platform_
+device_count=N`` set before Python starts (the CI mesh leg does this);
+nothing here touches jax device state at import time.
 
 Metrics (docs/METRICS.md §mesh):
 
@@ -55,8 +55,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from .. import obs
@@ -96,9 +95,7 @@ def partition_mesh(devices: Optional[int] = None) -> Mesh:
     n = len(devs) if devices is None else int(devices)
     if n < 1 or n > len(devs):
         raise ValueError(
-            f"partition mesh wants {n} devices but {len(devs)} are visible; "
-            f"launchers must set XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count before importing jax")
+            f"partition mesh wants {n} devices but {len(devs)} are visible")
     return Mesh(np.asarray(devs[:n]), (PART_AXIS,))
 
 
@@ -320,7 +317,7 @@ def run_chain_stack(mesh: Mesh, tiers, bounds, idx_pad, n_live, preds,
     dev_aggs = tuple((next(it), next(it)) for _ in aggds)
     fn = _chain_fn(mesh, tiers_struct, len(preds), len(aggds),
                    total_p2, live_p2)
-    with enable_x64():
+    with jax.enable_x64(True):
         outs = fn(dev_tiers, bounds, dev_idx, n_live, dev_preds, dev_aggs)
         n_cand, n_found, n_valid, mask, per_col = jax.device_get(outs)
     mask_np = np.asarray(mask)
@@ -458,7 +455,7 @@ def batched_range_masks(batches: Sequence[Any],
     flat = list(datas) + list(valids)
     ops, missed = fetch_sharded(flat, mesh)
     fn = _mask_fn(mesh, k, live_p2)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = np.asarray(jax.device_get(
             fn(tuple(ops[:k]), tuple(ops[k:]), tuple(los), tuple(his))))
     _record_dispatch("spmd_range_mask", h2d=missed, d2h=[out])
@@ -532,7 +529,7 @@ def batched_select_aggregate(batches: Sequence[Any],
     flat = list(datas) + list(valids) + adatas + avalids
     ops, missed = fetch_sharded(flat, mesh)
     fn = _agg_fn(mesh, k, m, live_p2)
-    with enable_x64():
+    with jax.enable_x64(True):
         outs = fn(tuple(ops[:k]), tuple(ops[k:2 * k]),
                   tuple(los), tuple(his),
                   tuple(ops[2 * k:2 * k + m]), tuple(ops[2 * k + m:]))
@@ -646,7 +643,7 @@ def exchange_batches(cparts: Sequence[Any], keys: Sequence[str], p: int
                 valid_s[i, j, :z - a] = v_srt[a:z]
         send.extend((data_s, valid_s))
     fn = _exchange_fn(mesh, len(send), cap)
-    with enable_x64():
+    with jax.enable_x64(True):
         recv = [np.asarray(a) for a in jax.device_get(fn(*send))]
     _record_dispatch("spmd_exchange", h2d=send, d2h=recv)
     _note_spmd(mesh, p)
@@ -716,7 +713,7 @@ def _collective_merge(parts: np.ndarray, op: str,
                            ident, dtype=parts.dtype)
         parts = np.concatenate([parts, fill])
     fn = _merge_fn(m, op)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = np.asarray(jax.device_get(fn(parts)))
     _record_dispatch(f"spmd_merge_{op}", d2h=[out])
     _note_spmd(m, parts.shape[0])

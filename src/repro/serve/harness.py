@@ -50,6 +50,7 @@ import itertools
 import queue
 import threading
 import time
+import traceback
 from collections import deque
 from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
@@ -484,9 +485,11 @@ class QueryWorker(threading.Thread):
                             lost_c.inc()
                     else:
                         self.h.executor_query(self.idx + i, req)
-                except Exception as e:            # noqa: BLE001
+                except Exception:                 # noqa: BLE001
+                    # the worker must stay up; the run reports the failure
+                    # (ServeReport.query_errors) and its callers fail on it
                     req.outcome = "error"
-                    self.errors.append(f"{type(e).__name__}: {e}")
+                    self.errors.append(traceback.format_exc())
                 lat.observe(time.perf_counter() - t0)
                 self.queries += 1
                 i += 1
@@ -585,8 +588,12 @@ class ServeHarness:
                  joint_window: int = 4096,
                  deadline_s: Optional[float] = None,
                  admission_timeout: float = 0.2,
-                 profile_every: int = 16, profile_ring: int = 64):
+                 profile_every: int = 16, profile_ring: int = 64,
+                 query_plan: Optional[Callable[[int], Any]] = None,
+                 vectorize: bool = False):
         self.dataset = dataset
+        self.query_plan = query_plan
+        self.vectorize = bool(vectorize)
         self.n_ingest = int(n_ingest)
         self.n_query = int(n_query)
         self.pump_batch = int(pump_batch)
@@ -666,18 +673,30 @@ class ServeHarness:
         over a pinned snapshot.  With a tracked request the pin is taken
         explicitly so its cost lands in the pin phase, and the executor
         runs against the snapshot facade (``run_query`` skips re-pinning
-        an already-pinned ``DatasetSnapshot``)."""
+        an already-pinned ``DatasetSnapshot``).
+
+        ``query_plan(salt)``, when the harness has one, builds the plan;
+        with ``vectorize`` it runs on the columnar engine, and a plan that
+        falls back to the row engine is an error."""
         ph = req.phase if req is not None else _null_phase
-        pk = self.dataset.pk
-        r = salt % 7
-        plan = A.select(A.scan(self.dataset.name),
-                        pred=lambda row: row[pk] % 7 == r,
-                        fields=[pk])
+        if self.query_plan is not None:
+            plan = self.query_plan(salt)
+        else:
+            pk = self.dataset.pk
+            r = salt % 7
+            plan = A.select(A.scan(self.dataset.name),
+                            pred=lambda row: row[pk] % 7 == r,
+                            fields=[pk])
         with ph("pin"):
             snap = self.dataset.pin()
         try:
             with ph("execute"):
-                rows, _ = run_query(plan, {self.dataset.name: snap})
+                rows, ex = run_query(plan, {self.dataset.name: snap},
+                                     vectorize=self.vectorize)
+                if self.vectorize and ex.stats.fallback_reasons:
+                    raise RuntimeError(
+                        "columnar query fell back: "
+                        f"{dict(ex.stats.fallback_reasons)}")
             with ph("result"):
                 return len(rows)
         finally:

@@ -573,15 +573,14 @@ class PartitionedDataset:
     def _positions_mask(parts: List[np.ndarray], total: int,
                         idx: np.ndarray) -> np.ndarray:
         """Candidate bitmap over live scan positions from per-tier posting
-        segments: one scatter pass (the ngram T-occurrence kernel at
-        threshold 1) over the storage concat, then the newest-wins
-        selection — a stale old-version hit is simply never selected."""
-        from ..kernels.fuzzy_ops import t_occurrence_mask
-        parts = [p for p in parts if len(p)]
-        if not parts:
-            return np.zeros(len(idx), dtype=bool)
-        all_pos = np.concatenate(parts) if len(parts) > 1 else parts[0]
-        return t_occurrence_mask(all_pos, total, 1)[idx]
+        segments: one host bool scatter over the storage concat (the
+        positions are on the host already, and so is the answer), then
+        the newest-wins selection — a stale old-version hit is simply
+        never selected."""
+        mask = np.zeros(total, dtype=bool)
+        for p in parts:
+            mask[p] = True
+        return mask[idx]
 
     def secondary_candidate_mask(self, i: int, fld: str, lo: Any, hi: Any,
                                  _view: Optional[LSMView] = None
@@ -748,13 +747,13 @@ class PartitionedDataset:
                              ) -> np.ndarray:
         """T-occurrence candidate bitmap over partition ``i``'s scan
         positions (aligned with ``scan_partition_batch`` /
-        ``partition_pk_array``): gram-hit posting segments from every
-        storage tier concatenate into one position array and a single
-        fused count kernel keeps positions with >= T hits.  T <= 0 means
-        the index cannot prune — every row with an indexable value is a
-        candidate."""
+        ``partition_pk_array``): the query grams' posting segments in
+        every storage tier feed one fused count kernel over the tiers'
+        device-resident positions, which keeps positions with >= T hits.
+        T <= 0 means the index cannot prune — every row with an indexable
+        value is a candidate."""
         from ..fuzzy.ngram import query_grams
-        from ..kernels.fuzzy_ops import t_occurrence_mask
+        from ..kernels.fuzzy_ops import ngram_t_occurrence
         if fld not in self._ngram_specs:
             raise adm.ValidationError(f"no ngram index on {self.name}.{fld}")
         view = _view if _view is not None else self._view(i)
@@ -768,10 +767,9 @@ class PartitionedDataset:
             for off, p in sources:
                 has[off:off + p.n_rows] = p.has_value
             return has[idx]
-        parts = [off + p.hit_positions(qh) for off, p in sources]
-        all_pos = np.concatenate(parts) if parts \
-            else np.zeros(0, dtype=np.int64)
-        return t_occurrence_mask(all_pos, total, threshold)[idx]
+        tiers = [(off, p.positions, p.padded_positions())
+                 + p.hit_segments(qh) for off, p in sources]
+        return ngram_t_occurrence(tiers, total, threshold)[idx]
 
     def ngram_search_partition(self, i: int, fld: str, spec: Tuple,
                                _view: Optional[LSMView] = None

@@ -8,6 +8,7 @@ import random
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.columnar.batch import ColumnBatch
 from repro.columnar.schema import ColumnSchema
 from repro.configs.tinysocial import build_dataverse, message_type, user_type
@@ -153,12 +154,39 @@ def test_kernel_range_mask_and_fused_aggregate(rng):
     assert res["cnts"][1] == int(ok_y.sum())
     assert res["sums"][1] == pytest.approx(float(y[ok_y].sum()))
 
-    # the Pallas kernel (interpret mode off-TPU) agrees to f32 tolerance
-    rp = K.fused_filter_aggregate(preds, [(x, xv), (y, yv)], n,
+    # the Pallas kernel (interpret mode off-TPU) takes the int32-exact
+    # columns and agrees exactly; the float column keeps the whole call
+    # on the exact x64 core
+    before = obs.snapshot().get(
+        "kernel.fused_filter_aggregate.pallas_dispatches", 0)
+    rp = K.fused_filter_aggregate(preds, [(x, xv)], n,
                                   force_pallas=True, interpret=True)
-    assert rp["count"] == res["count"] and rp["cnts"] == res["cnts"]
-    assert rp["sums"][0] == pytest.approx(res["sums"][0], rel=1e-5)
-    assert rp["mins"][0] == pytest.approx(res["mins"][0], rel=1e-5)
+    assert obs.snapshot()[
+        "kernel.fused_filter_aggregate.pallas_dispatches"] == before + 1
+    assert rp["count"] == res["count"]
+    assert (rp["sums"][0], rp["mins"][0], rp["maxs"][0], rp["cnts"][0]) \
+        == (res["sums"][0], res["mins"][0], res["maxs"][0], res["cnts"][0])
+    rm = K.fused_filter_aggregate(preds, [(x, xv), (y, yv)], n,
+                                  force_pallas=True, interpret=True)
+    assert rm == res
+
+    # int32 values whose absolute sum does not fit int32: count/min/max
+    # alone still take the kernel, a read sum keeps the exact core
+    big = rng.integers(2**29, 2**30, n)
+    ok_b = oracle & xv
+    for summed, on_kernel in (([False], 1), ([True], 0)):
+        before = obs.snapshot().get(
+            "kernel.fused_filter_aggregate.pallas_dispatches", 0)
+        rb = K.fused_filter_aggregate(preds, [(big, xv)], n, summed=summed,
+                                      force_pallas=True, interpret=True)
+        assert obs.snapshot().get(
+            "kernel.fused_filter_aggregate.pallas_dispatches", 0) \
+            == before + on_kernel
+        assert (rb["count"], rb["mins"][0], rb["maxs"][0], rb["cnts"][0]) \
+            == (int(oracle.sum()), int(big[ok_b].min()),
+                int(big[ok_b].max()), int(ok_b.sum()))
+        if summed[0]:
+            assert rb["sums"][0] == int(big[ok_b].sum())
 
     # unbounded sides and empty results
     assert K.range_mask([(x, xv, None, None)], n).sum() == xv.sum()
@@ -421,9 +449,12 @@ def test_schema_inference_unifies_open_fields():
     s.observe_value("x", 1)
     assert s.kind("x") == "i64"
     s.observe_value("x", 2.5)
-    assert s.kind("x") == "f64"
-    s.observe_value("x", "oops")
-    assert s.kind("x") == "obj"
+    assert s.kind("x") == "obj"       # exact: ints must decode as ints
+    s.observe_value("y", 2.5)
+    s.observe_value("y", 3.5)
+    assert s.kind("y") == "f64"
+    s.observe_value("y", "oops")
+    assert s.kind("y") == "obj"
 
 
 # ---------------------------------------------------------------------------

@@ -28,7 +28,8 @@ from repro.storage.query import run_query
 DOC = pathlib.Path(__file__).resolve().parents[1] / "docs" / "METRICS.md"
 
 # metric-name leaves that may follow a parametrized segment
-_KERNEL_LEAVES = ("dispatches", "h2d_bytes", "d2h_bytes")
+_KERNEL_LEAVES = ("dispatches", "pallas_dispatches", "h2d_bytes",
+                  "d2h_bytes")
 
 
 def _canon(name: str) -> str:

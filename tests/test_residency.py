@@ -25,8 +25,10 @@ from repro.storage.query import run_query
 @pytest.fixture(autouse=True)
 def _fused_enabled():
     """Every test starts with the fused path enabled (a test that turns
-    it off must not leak the switch into the rest of the suite)."""
+    it off must not leak the switch into the rest of the suite) and with
+    no plan shape seen, whatever ran earlier in this process."""
     PC.set_enabled(True)
+    PC.plan_cache.clear()
     yield
     PC.set_enabled(True)
 

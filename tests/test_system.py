@@ -31,7 +31,7 @@ def test_connector_collective_twins():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.runtime import collectives as C
 
         from repro.runtime.mesh import make_mesh
@@ -64,7 +64,7 @@ def test_distributed_logsumexp_merge():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.runtime.collectives import distributed_logsumexp_merge
         from repro.kernels import ref as kref
 
