@@ -1,13 +1,11 @@
 """Shared benchmark timing — one best-of-N implementation for every
-benchmark module, built on the obs tracer.
+benchmark module.
 
 Every bench used to carry its own copy of the ``perf_counter`` best-of-N
 loop (and ``fuzzy_bench`` timed its wall clock with non-monotonic
 ``time.time()``).  This module is the single source of truth:
 
-  timed(fn)      — best-of-N wall time for a callable; each repetition
-                   runs under a ``bench.rep`` obs span so enabling the
-                   tracer yields a Chrome-trace of the bench itself.
+  timed(fn)      — best-of-N wall time for a callable.
   stopwatch()    — context manager for one-shot sections (ingest loops,
                    end-to-end pipelines); monotonic by construction.
 
@@ -18,8 +16,6 @@ from __future__ import annotations
 
 import time
 from typing import Any, Callable, Optional, Tuple
-
-from repro import obs
 
 __all__ = ["timed", "stopwatch", "Stopwatch"]
 
@@ -40,14 +36,11 @@ def timed(fn: Callable[[], Any], repeat: int = 3, warmup: int = 0,
             block(out)
     best = float("inf")
     for _ in range(max(1, repeat)):
-        with obs.span("bench.rep") as sp:
-            t0 = time.perf_counter()
-            out = fn()
-            if block is not None:
-                block(out)
-            dt = time.perf_counter() - t0
-            sp.set("seconds", dt)
-        best = min(best, dt)
+        t0 = time.perf_counter()
+        out = fn()
+        if block is not None:
+            block(out)
+        best = min(best, time.perf_counter() - t0)
     return out, best
 
 

@@ -126,7 +126,10 @@ def try_lower(op: PhysicalOp, ex: Any) -> Optional[Callable[[], list]]:
 
     def run() -> list:
         with _obs.span("columnar." + op.kind):
-            return [b.to_rows() for b in node()]
+            batches = node()
+            with _obs.span("columnar.to_rows",
+                           rows=_total(batches)):
+                return [b.to_rows() for b in batches]
     return run
 
 
@@ -645,8 +648,9 @@ def _compile_index_path(op: PhysicalOp, ex: Any,
             if is_fuzzy:
                 # T-occurrence candidate bitmap, already position-aligned
                 # with the partition's scan batch — no PK intersection
-                mask = ds.ngram_candidate_mask(i, search.attrs["field"],
-                                               fuzzy_spec)
+                with _obs.span("fuzzy.candidates"):
+                    mask = ds.ngram_candidate_mask(i, search.attrs["field"],
+                                                   fuzzy_spec)
                 n_cand += int(mask.sum())
                 if not mask.any():
                     emit_empty()                 # no candidates
@@ -670,7 +674,8 @@ def _compile_index_path(op: PhysicalOp, ex: Any,
             if fuzzy_spec is not None and validate is not None:
                 # VERIFY: batched similarity kernels over the candidate
                 # positions' dictionary-coded column (per distinct value)
-                mask = verify_mask(batch, mask, fuzzy_spec, gram_k)
+                with _obs.span("fuzzy.verify"):
+                    mask = verify_mask(batch, mask, fuzzy_spec, gram_k)
             if validate is not None and (validate_ranges
                                          or (residual and pred is not None)):
                 got = O.index_post_validate(batch, mask, validate_ranges,

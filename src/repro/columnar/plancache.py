@@ -23,6 +23,11 @@ per-field tier shape tuples, predicate/aggregate dtypes.  The
 warm-up trace) vs. repeats (``plan_cache.hits``); the jit trace cache
 itself is the compiled artifact, so a hit is purely a dictionary probe.
 
+While tracing is enabled, each partition's dispatch shows its host and
+device halves as spans: ``chain.operands`` (the gather), ``chain.h2d``
+(the pool fetch), ``chain.device`` (the core through its
+``device_get``) and ``chain.assemble`` (the host-side result).
+
 Declines are cheap and total: any input the fused core cannot represent
 exactly (unordered key dictionary, obj-degraded validate column, fuzzy
 chains, live/storage mismatches mid-race) returns None and the caller
@@ -343,7 +348,8 @@ def compile_chain(ds: Any, *, chain_ops: Tuple[str, ...], search_field: str,
             ) -> Optional[ChainResult]:
         if not plan_cache.enabled:
             return None
-        g = _gather(i, cols)
+        with obs.span("chain.operands"):
+            g = _gather(i, cols)
         if g is None or g.get("empty"):
             return None
         tiers, bounds = g["tiers"], g["bounds"]
@@ -365,7 +371,8 @@ def compile_chain(ds: Any, *, chain_ops: Tuple[str, ...], search_field: str,
             flat.extend((d, v))
         for d, v in agg_arrays:
             flat.extend((d, v))
-        ops, missed = _pool.fetch(flat)
+        with obs.span("chain.h2d"):
+            ops, missed = _pool.fetch(flat)
         it = iter(ops)
         dev_tiers = tuple(tuple(next(it) for _ in fp) for fp in tiers)
         dev_idx = next(it)
@@ -379,7 +386,7 @@ def compile_chain(ds: Any, *, chain_ops: Tuple[str, ...], search_field: str,
             tuple((np.asarray(a, np.int64), np.asarray(b, np.int64),
                    np.asarray(off, np.int64)) for a, b, off in fb)
             for fb in bounds)
-        with jax.enable_x64(True):
+        with obs.span("chain.device"), jax.enable_x64(True):
             outs = _chain_core(dev_tiers, dev_bounds, dev_idx,
                                np.asarray(n_live, np.int64),
                                tuple(dev_preds), dev_aggs,
@@ -387,9 +394,10 @@ def compile_chain(ds: Any, *, chain_ops: Tuple[str, ...], search_field: str,
             n_cand, n_found, n_valid, mask_d, per_col = jax.device_get(outs)
         mask_np = np.asarray(mask_d)
         _record_dispatch("fused_index_chain", h2d=missed, d2h=[mask_np])
-        return _assemble(g["batch"], n_live, mask_np, per_col,
-                         g["agg_meta"], int(n_cand), int(n_found),
-                         int(n_valid))
+        with obs.span("chain.assemble"):
+            return _assemble(g["batch"], n_live, mask_np, per_col,
+                             g["agg_meta"], int(n_cand), int(n_found),
+                             int(n_valid))
 
     def run_all(cols: Optional[Sequence[str]]
                 ) -> Optional[List[Optional[ChainResult]]]:
@@ -407,7 +415,8 @@ def compile_chain(ds: Any, *, chain_ops: Tuple[str, ...], search_field: str,
         gathered: List[Optional[Dict[str, Any]]] = []
         entries: List[Tuple[int, Dict[str, Any]]] = []
         for i in range(P):
-            g = _gather(i, cols)
+            with obs.span("chain.operands"):
+                g = _gather(i, cols)
             gathered.append(g)
             if g is not None and not g.get("empty"):
                 entries.append((i, g))
@@ -494,17 +503,20 @@ def compile_chain(ds: Any, *, chain_ops: Tuple[str, ...], search_field: str,
             vv = sc.stack([g["agg_arrays"][j][1] for _i, g in entries],
                           rows, live_p2, np.bool_, fill=False)
             st_aggs.append((dd, vv))
-        n_cand_a, n_found_a, n_valid_a, mask_a, per_col_a = \
-            spmd.run_chain_stack(mesh, tuple(st_tiers), tuple(st_bounds),
-                                 idx_st, n_live_v, tuple(st_preds),
-                                 tuple(st_aggs), total_p2, live_p2,
-                                 len(entries))
+        with obs.span("chain.device"):
+            n_cand_a, n_found_a, n_valid_a, mask_a, per_col_a = \
+                spmd.run_chain_stack(mesh, tuple(st_tiers),
+                                     tuple(st_bounds), idx_st, n_live_v,
+                                     tuple(st_preds), tuple(st_aggs),
+                                     total_p2, live_p2, len(entries))
         out: List[Optional[ChainResult]] = [None] * P
         for r, (i, g) in enumerate(entries):
             per_col = [tuple(x[r] for x in pc) for pc in per_col_a]
-            out[i] = _assemble(g["batch"], g["n_live"], mask_a[r],
-                               per_col, g["agg_meta"], int(n_cand_a[r]),
-                               int(n_found_a[r]), int(n_valid_a[r]))
+            with obs.span("chain.assemble"):
+                out[i] = _assemble(g["batch"], g["n_live"], mask_a[r],
+                                   per_col, g["agg_meta"],
+                                   int(n_cand_a[r]), int(n_found_a[r]),
+                                   int(n_valid_a[r]))
         return out
 
     run.run_all = run_all

@@ -85,8 +85,6 @@ __all__ = ["Component", "LSMIndex", "LSMView", "TieredMergePolicy",
 _FLUSH_S = _obs.histogram("lsm.flush_seconds")
 _MERGE_S = _obs.histogram("lsm.merge_seconds")
 _POSTINGS_S = _obs.histogram("lsm.postings_build_seconds")
-_COMP_ROWS = _obs.histogram("lsm.component_rows")
-_COMP_BYTES = _obs.histogram("lsm.component_bytes")
 _FLUSHES = _obs.counter("lsm.flushes")
 _MERGES = _obs.counter("lsm.merges")
 _ROWS_INGESTED = _obs.counter("lsm.rows_ingested")
@@ -94,10 +92,11 @@ _ROWS_FLUSHED = _obs.counter("lsm.rows_flushed")
 _ROWS_MERGED = _obs.counter("lsm.rows_merged")
 _BYTES_FLUSHED = _obs.counter("lsm.bytes_flushed")
 _BYTES_MERGED = _obs.counter("lsm.bytes_merged")
-_COMPONENTS = _obs.gauge("lsm.components")
 _PINS = _obs.counter("lsm.pins")
 _DEFERRED = _obs.counter("lsm.deferred_retires")
 _PINNED_G = _obs.gauge("lsm.pinned_snapshots")
+_REPLAY_S = _obs.histogram("lsm.wal_replay_seconds")
+_REPLAYED = _obs.counter("lsm.wal_records_replayed")
 
 
 def _pool_release(comp: "Component") -> None:
@@ -689,14 +688,11 @@ class LSMIndex:
             _FLUSHES.inc()
             _ROWS_FLUSHED.inc(comp.size)
             _BYTES_FLUSHED.inc(nbytes)
-            _COMP_ROWS.observe(comp.size)
-            _COMP_BYTES.observe(nbytes)
             # ingest accounting at flush granularity (never per-row): the
             # delta of this index's insert+delete counters since last flush
             ingested = self.stats["inserts"] + self.stats["deletes"]
             _ROWS_INGESTED.inc(ingested - self._ingest_counted)
             self._ingest_counted = ingested
-            _COMPONENTS.set(sum(1 for c in self.components if c.valid))
             self._maybe_merge()
             return comp
 
@@ -778,9 +774,6 @@ class LSMIndex:
         _MERGES.inc()
         _ROWS_MERGED.inc(out.size)
         _BYTES_MERGED.inc(nbytes)
-        _COMP_ROWS.observe(out.size)
-        _COMP_BYTES.observe(nbytes)
-        _COMPONENTS.set(sum(1 for c in self.components if c.valid))
         return out
 
     # -- read path ----------------------------------------------------------
@@ -852,11 +845,18 @@ def recover(components: Sequence[Component], wal: Sequence[WALRecord],
     idx.components = [c for c in components if c.valid]
     idx.wal = list(wal)
     idx._lsn = itertools.count(len(idx.wal))
-    for rec in wal:
-        if rec.lsn < replay_from_lsn:
-            continue
-        if rec.op == "insert":
-            idx.memtable[rec.key] = rec.row
-        elif rec.op == "delete":
-            idx.memtable[rec.key] = TOMBSTONE
+    t0 = time.perf_counter()
+    n = 0
+    with _obs.span("lsm.wal_replay") as sp:
+        for rec in wal:
+            if rec.lsn < replay_from_lsn:
+                continue
+            n += 1
+            if rec.op == "insert":
+                idx.memtable[rec.key] = rec.row
+            elif rec.op == "delete":
+                idx.memtable[rec.key] = TOMBSTONE
+        sp.set("records", n)
+    _REPLAY_S.observe(time.perf_counter() - t0)
+    _REPLAYED.inc(n)
     return idx

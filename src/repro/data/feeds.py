@@ -297,17 +297,19 @@ class Feed:
         adaptor to the true source offset even when UDFs filter records
         — otherwise replay would re-deliver already-processed records."""
         with _obs.span("feed.pump." + self.name) as sp:
-            if self.adaptor is not None:
-                recs = self.adaptor.next_batch(n)
-            else:
-                recs = self.source_joint.consume(self.name, n)
+            with _obs.span("feed.intake"):
+                if self.adaptor is not None:
+                    recs = self.adaptor.next_batch(n)
+                else:
+                    recs = self.source_joint.consume(self.name, n)
             intake = len(recs)
             for udf in self.udfs:
                 recs = [udf(r) for r in recs]
                 recs = [r for r in recs if r is not None]  # UDFs may filter
             self.joint.publish(recs)
             if self.store is not None:
-                self.store(recs)
+                with _obs.span("feed.store"):
+                    self.store(recs)
             self.cursor += intake
             self.last_intake = intake
             sp.set("records", len(recs))

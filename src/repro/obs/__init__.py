@@ -13,19 +13,51 @@ per-call/per-batch paths only, never per-row ones.
 Span naming convention (``obs.span(name, **attrs)``):
 
   exec.<OP_KIND>          one executor operator, row/fallback engine
-                          (storage/query.Executor.execute_op)
+                          (storage/query.Executor.execute_op); the query's
+                          root ``exec.*`` span stays at depth 0
+  query.optimize          rewrite + physical planning of one query
+                          (storage/query.run_query), beside the root
+                          ``exec.*`` span, not above it
   columnar.<OP_KIND>      one columnar-lowered operator closure
                           (columnar/lower; the Figure-6 index chain is
                           one ``columnar.PRIMARY_INDEX_LOOKUP`` /
                           ``columnar.POST_VALIDATE_SELECT`` span)
+  columnar.to_rows        the row decode at the columnar boundary (attrs:
+                          rows)
+  chain.operands          one partition's fused-chain operand gather
+                          (tier probes, scan batch, range predicates,
+                          pow2 padding; columnar/plancache)
+  chain.h2d               the device-pool fetch of those operands
+  chain.device            the chain core's dispatch through its
+                          ``device_get``: the host waits on the device
+  chain.assemble          the chain result's host assembly
+  fuzzy.candidates        ngram T-occurrence candidate bitmap of one
+                          partition (legacy per-partition chain path)
+  fuzzy.verify            batched similarity verification of one
+                          partition's candidates
   lsm.flush               one memtable flush (attrs: rows, bytes)
   lsm.merge               one k-way component merge (attrs: rows, bytes,
                           components)
   lsm.postings_build      ngram/secondary CSR postings build for one
                           component field (attrs: field)
-  feed.pump.<feed>        one intake -> compute -> store cycle (attrs:
+  lsm.wal_replay          one index's WAL replay at recovery (attrs:
                           records)
-  bench.rep               one repetition inside benchmarks/_timing.timed
+  storage.insert_batch    one ``PartitionedDataset.insert_batch``, with
+                          children ``storage.insert_validate`` (validate,
+                          open-schema observe, route) and
+                          ``storage.insert_apply`` (gate, then the
+                          partitions' WAL+memtable pass and any flush or
+                          merge it triggers)
+  storage.memtable_shred  the memtable tail's shred in a columnar
+                          partition scan (attrs: rows)
+  feed.pump.<feed>        one intake -> compute -> store cycle (attrs:
+                          records), with children ``feed.intake`` (the
+                          adaptor or joint) and ``feed.store`` (the store
+                          call, backpressure included)
+
+Every span carries ``sid``, ``parent``, ``trace_id`` (the thread's
+``obs.request`` context: one per query, opened by ``run_query`` or by
+the serve harness's request tracker) and ``cpu_s`` (thread CPU time).
 
 Kernel spans are not opened per dispatch (too hot); instead
 ``record_dispatch`` *attributes* dispatch counts and byte totals onto
@@ -54,12 +86,14 @@ import numpy as np
 from . import metrics, tracer
 from .metrics import counter, gauge, histogram, snapshot, typed_snapshot
 from .tracer import (Span, clear, current, disable, dump_trace, enable,
-                     enabled, events, span, to_chrome)
+                     enabled, events, new_trace_id, request, span,
+                     to_chrome)
 from . import export
 from .export import (ExporterServer, MetricsSampler, TimeSeriesRing,
                      render_prometheus, serve_http)
 
-__all__ = ["metrics", "tracer", "export", "span", "enable", "disable",
+__all__ = ["metrics", "tracer", "export", "span", "request",
+           "new_trace_id", "enable", "disable",
            "enabled", "current", "events", "clear", "dump_trace",
            "to_chrome", "counter", "gauge", "histogram", "snapshot",
            "typed_snapshot", "reset", "record_dispatch", "record_retrace",

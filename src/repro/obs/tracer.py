@@ -14,34 +14,49 @@ therefore nest per thread; ``current()`` exposes the innermost open span
 so other instrumentation (``obs.record_dispatch``) can attribute kernel
 dispatches and transfer bytes to the operator that triggered them.
 
+Each span carries a process-unique ``sid``, the ``parent`` sid of the
+span enclosing it on its thread (None at the root), the ``trace_id`` of
+the thread's request context (``request()``; None outside one) and
+``cpu_s``, the thread CPU time (``time.thread_time``) between enter and
+exit: a span whose ``cpu_s`` falls well short of its wall time spent
+the difference waiting (GIL, device, a lock, a queue).
+
+While tracing is enabled each span also opens a
+``jax.profiler.TraceAnnotation`` of the same name (``trace_id`` as its
+metadata), so the program's spans land on the profiler's host plane on
+the device trace's own clock in any ``jax.profiler`` session.
+
 ``dump_trace(path)`` writes the finished spans as a Chrome trace-event
-JSON file (``ph: "X"`` complete events, microsecond timestamps), loadable
-in ``chrome://tracing`` / Perfetto, so a whole feed -> flush -> merge ->
+JSON file (``ph: "X"`` complete events, microsecond timestamps; ``sid``,
+``parent``, ``trace_id`` and ``cpu_s`` in ``args``), loadable in
+``chrome://tracing`` / Perfetto, so a whole feed -> flush -> merge ->
 query run is inspectable on one timeline.
 
-Span naming convention (see ``obs.__init__`` for the full registry):
-
-  exec.<OP_KIND>       row/fallback executor operator (storage/query)
-  columnar.<OP_KIND>   columnar-lowered operator (columnar/lower)
-  lsm.flush / lsm.merge / lsm.postings_build
-  feed.pump.<feed>     one intake->compute->store cycle
-  bench.rep            one repetition inside benchmarks/_timing.timed
+Span naming convention: see ``obs.__init__`` for the full registry.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Span", "span", "enable", "disable", "enabled", "current",
-           "clear", "events", "dump_trace", "to_chrome"]
+__all__ = ["Span", "span", "request", "new_trace_id", "enable", "disable",
+           "enabled", "current", "clear", "events", "dump_trace",
+           "to_chrome"]
 
 _enabled = False
 _lock = threading.Lock()
 _events: List["Span"] = []
 _tls = threading.local()
+# process-unique span ids and request ids (``next`` on a count is atomic
+# under the GIL)
+_sids = itertools.count(1)
+_trace_ids = itertools.count(1)
+# jax.profiler.TraceAnnotation, resolved by enable() (None: no jax)
+_annotation: Any = None
 # trace timestamps are perf_counter-relative to import time so every
 # thread shares one monotonic origin
 _T0 = time.perf_counter()
@@ -52,7 +67,8 @@ class Span:
     ``args``; ``add``/``set`` mutate them while the span is open (or
     after — spans are plain records)."""
 
-    __slots__ = ("name", "attrs", "t0", "t1", "tid", "depth")
+    __slots__ = ("name", "attrs", "t0", "t1", "tid", "depth", "sid",
+                 "parent", "trace_id", "cpu_s", "_ann")
 
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]] = None):
         self.name = name
@@ -61,6 +77,11 @@ class Span:
         self.t1 = 0.0
         self.tid = 0
         self.depth = 0
+        self.sid = 0
+        self.parent: Optional[int] = None
+        self.trace_id: Any = None
+        self.cpu_s = 0.0
+        self._ann: Any = None
 
     @property
     def duration(self) -> float:
@@ -77,8 +98,16 @@ class Span:
     def __enter__(self) -> "Span":
         stack = _stack()
         self.depth = len(stack)
+        self.parent = stack[-1].sid if stack else None
+        self.sid = next(_sids)
+        self.trace_id = getattr(_tls, "trace_id", None)
         self.tid = threading.get_ident()
         stack.append(self)
+        if _enabled and _annotation is not None:
+            self._ann = (_annotation(self.name) if self.trace_id is None
+                         else _annotation(self.name, trace_id=self.trace_id))
+            self._ann.__enter__()
+        self.cpu_s = time.thread_time()
         self.t0 = time.perf_counter()
         return self
 
@@ -86,6 +115,10 @@ class Span:
         # close even when the body raised: pop self (and, defensively,
         # anything opened above and leaked) so the stack never wedges
         self.t1 = time.perf_counter()
+        self.cpu_s = time.thread_time() - self.cpu_s
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         stack = _stack()
         while stack:
             if stack.pop() is self:
@@ -125,6 +158,61 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+class _Request:
+    """One request context on the calling thread: spans opened inside
+    carry ``trace_id``.  Pushes no span; restores the enclosing context
+    on exit."""
+
+    __slots__ = ("trace_id", "_prev")
+
+    def __init__(self, trace_id: Any):
+        self.trace_id = trace_id
+        self._prev: Any = None
+
+    def __enter__(self) -> Any:
+        self._prev = getattr(_tls, "trace_id", None)
+        _tls.trace_id = self.trace_id
+        return self.trace_id
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        _tls.trace_id = self._prev
+        return None
+
+
+class _KeepRequest:
+    """Shared no-op request context: keeps the thread's current id."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> Any:
+        return getattr(_tls, "trace_id", None)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        return None
+
+
+_KEEP = _KeepRequest()
+
+
+def new_trace_id() -> int:
+    """A process-unique request id (shared with the serve harness's
+    request tracker, so ids never collide in one trace)."""
+    return next(_trace_ids)
+
+
+def request(trace_id: Any = None):
+    """Context manager setting the thread's request id; the spans opened
+    inside carry it as ``trace_id``.  With no id it opens a fresh one,
+    unless the thread already has a request open or tracing is disabled
+    (then it is a shared no-op that keeps the current id).  ``with
+    request() as tid`` yields the id in force."""
+    if trace_id is None:
+        if not _enabled or getattr(_tls, "trace_id", None) is not None:
+            return _KEEP
+        trace_id = next(_trace_ids)
+    return _Request(trace_id)
+
+
 def _stack() -> List[Span]:
     s = getattr(_tls, "stack", None)
     if s is None:
@@ -141,7 +229,13 @@ def span(name: str, **attrs: Any):
 
 
 def enable() -> None:
-    global _enabled
+    global _enabled, _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        except ImportError:             # no jax: spans stay host-only
+            pass
     _enabled = True
 
 
@@ -191,8 +285,11 @@ def to_chrome(spans: List[Span]) -> Dict[str, Any]:
                 "dur": max(e.duration, 0.0) * 1e6,
                 "pid": 0,
                 "tid": e.tid % (1 << 31),
-                "args": {k: v for k, v in e.attrs.items()
-                         if isinstance(v, (int, float, str, bool))},
+                "args": dict(
+                    {k: v for k, v in e.attrs.items()
+                     if isinstance(v, (int, float, str, bool))},
+                    sid=e.sid, parent=e.parent, trace_id=e.trace_id,
+                    cpu_s=e.cpu_s),
             }
             for e in spans
         ],

@@ -40,8 +40,6 @@ Metrics (docs/METRICS.md §mesh):
                                 device exchange (connector repartition)
   mesh.shard<k>.h2d_bytes       counter: per-shard share of sharded
                                 uploads (``fetch_sharded``)
-  mesh.partitions_per_dispatch  histogram: stacked partition count per
-                                SPMD dispatch
 """
 
 from __future__ import annotations
@@ -78,7 +76,6 @@ _DISPATCHES = obs.counter("mesh.spmd_dispatches")
 _PARTITIONS = obs.counter("mesh.spmd_partitions")
 _FALLBACKS = obs.counter("mesh.spmd_fallbacks")
 _EXCH_ROWS = obs.counter("mesh.exchange_rows")
-_PART_HIST = obs.histogram("mesh.partitions_per_dispatch")
 
 
 # ---------------------------------------------------------------------------
@@ -163,7 +160,6 @@ def note_fallback() -> None:
 def _note_spmd(mesh: Mesh, n_parts: int) -> None:
     _DISPATCHES.inc()
     _PARTITIONS.inc(n_parts)
-    _PART_HIST.observe(n_parts)
 
 
 # ---------------------------------------------------------------------------

@@ -120,14 +120,19 @@ class StridedRecordAdaptor(Adaptor):
 class BoundedSink:
     """Feed store stage pushing micro-batches onto a bounded queue.  A
     full queue *blocks* the pump (backpressure) instead of dropping —
-    the fix for silent feed-side loss under a slow storage stage."""
+    the fix for silent feed-side loss under a slow storage stage.  The
+    time each put blocks lands in ``serve.sink.put_wait_seconds``."""
 
     def __init__(self, q: "queue.Queue[List[Any]]"):
         self.q = q
+        self._wait = _obs.histogram("serve.sink.put_wait_seconds")
 
     def __call__(self, records: Sequence[Any]) -> None:
         if records:
-            self.q.put(list(records), block=True)
+            batch = list(records)
+            t0 = time.perf_counter()
+            self.q.put(batch, block=True)
+            self._wait.observe(time.perf_counter() - t0)
 
 
 # ---------------------------------------------------------------------------
@@ -249,6 +254,7 @@ class RequestRecord:
     kernel: Dict[str, int] = field(default_factory=dict)
     spans: List[_tracer.Span] = field(default_factory=list)
     _root: Optional[_tracer.Span] = None
+    _request: Any = None          # the thread's obs.request context
 
     @contextmanager
     def phase(self, name: str) -> Iterator[None]:
@@ -257,8 +263,7 @@ class RequestRecord:
         ``obs.record_dispatch`` attributes kernel traffic to it."""
         sp: Optional[_tracer.Span] = None
         if self.profiled:
-            sp = _tracer.Span(f"serve.phase.{name}",
-                              {"trace_id": self.trace_id, "kind": self.kind})
+            sp = _tracer.Span(f"serve.phase.{name}", {"kind": self.kind})
             sp.__enter__()
         t0 = time.perf_counter()
         try:
@@ -288,7 +293,7 @@ class RequestTracker:
         self.deadline_s = deadline_s
         self.profile_every = max(0, int(profile_every))
         self.profiles: Deque[RequestRecord] = deque(maxlen=int(profile_ring))
-        self._ids = itertools.count(1)
+        self._seq = itertools.count(1)      # 1-in-N sampling sequence
         self._lock = threading.Lock()
         self.attained = 0
         self.missed = 0
@@ -308,14 +313,19 @@ class RequestTracker:
                          for p in PHASES if p != "queue_wait"}
 
     def begin(self, kind: str) -> RequestRecord:
-        tid = next(self._ids)
-        profiled = self.profile_every > 0 and tid % self.profile_every == 0
+        """Start one request on the calling thread: its trace id becomes
+        the thread's ``obs.request`` context until ``settle``, so every
+        program span the request opens carries it."""
+        tid = _obs.new_trace_id()
+        profiled = self.profile_every > 0 \
+            and next(self._seq) % self.profile_every == 0
         rec = RequestRecord(trace_id=tid, kind=kind, profiled=profiled,
                             t0=time.perf_counter())
+        rec._request = _obs.request(tid)
+        rec._request.__enter__()
         if profiled:
             self._g_profiled.inc()
-            rec._root = _tracer.Span("serve.request",
-                                     {"trace_id": tid, "kind": kind})
+            rec._root = _tracer.Span("serve.request", {"kind": kind})
             rec._root.__enter__()
         return rec
 
@@ -359,6 +369,9 @@ class RequestTracker:
             rec.spans.append(root)
             rec._root = None
             self.profiles.append(rec)
+        if rec._request is not None:
+            rec._request.__exit__(None, None, None)
+            rec._request = None
 
     def offered(self) -> int:
         with self._lock:

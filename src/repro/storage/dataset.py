@@ -48,11 +48,13 @@ from __future__ import annotations
 
 import itertools
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs as _obs
 from ..core import adm
 from ..core.functions import cells_covering_circle
 from ..core.lsm import LSMIndex, LSMView, TOMBSTONE, TieredMergePolicy, \
@@ -63,6 +65,13 @@ from ..columnar.schema import ColumnSchema
 
 __all__ = ["PartitionedDataset", "DatasetSnapshot", "hash_partition",
            "hash_partition_array"]
+
+
+# process-wide ingest and recovery metrics (docs/METRICS.md), resolved
+# once: one update per batch or per scan, never per row
+_INSERT_VALIDATE_S = _obs.histogram("storage.insert_validate_seconds")
+_INSERT_APPLY_S = _obs.histogram("storage.insert_apply_seconds")
+_MEM_SHRED_S = _obs.histogram("storage.memtable_shred_seconds")
 
 
 def hash_partition(key: Any, num_partitions: int) -> int:
@@ -271,6 +280,31 @@ class PartitionedDataset:
         unindexed ones — no per-record old-version lookups.  This is the
         feed store path: micro-batches flow straight into memory
         components and flush columnar."""
+        with _obs.span("storage.insert_batch", records=len(records)):
+            t0 = time.perf_counter()
+            with _obs.span("storage.insert_validate"):
+                buckets = self._route_batch(records)
+            t1 = time.perf_counter()
+            _INSERT_VALIDATE_S.observe(t1 - t0)
+            with _obs.span("storage.insert_apply"):
+                # shared gate: concurrent batches still run in parallel,
+                # but a snapshot pin (exclusive) can never observe half
+                # of this batch
+                self._batch_gate.acquire_shared()
+                try:
+                    for part, (ks, rs) in zip(self.partitions, buckets):
+                        if ks:
+                            part.primary.insert_batch(ks, rs)
+                finally:
+                    self._batch_gate.release_shared()
+            _INSERT_APPLY_S.observe(time.perf_counter() - t1)
+        with self._stats_lock:
+            self.stats["inserts"] += len(records)
+
+    def _route_batch(self, records: Sequence[Dict[str, Any]]
+                     ) -> List[Tuple[List[Any], List[Dict[str, Any]]]]:
+        """Validate a batch, observe its open fields, and route it: one
+        (keys, records) bucket per partition."""
         P = self.num_partitions
         buckets: List[Tuple[List[Any], List[Dict[str, Any]]]] = \
             [([], []) for _ in range(P)]
@@ -299,17 +333,7 @@ class PartitionedDataset:
                              else hash_partition(key, P)]
             ks.append(key)
             rs.append(rec)
-        # shared gate: concurrent batches still run in parallel, but a
-        # snapshot pin (exclusive) can never observe half of this batch
-        self._batch_gate.acquire_shared()
-        try:
-            for part, (ks, rs) in zip(self.partitions, buckets):
-                if ks:
-                    part.primary.insert_batch(ks, rs)
-        finally:
-            self._batch_gate.release_shared()
-        with self._stats_lock:
-            self.stats["inserts"] += len(records)
+        return buckets
 
     def delete(self, key: Any) -> bool:
         part = self.partitions[hash_partition(key, self.num_partitions)]
@@ -515,9 +539,12 @@ class PartitionedDataset:
         batches: List[ColumnBatch] = []
         mem = view.memtable
         if mem:
-            batches.append(ColumnBatch.from_rows(
-                [({} if r is TOMBSTONE else r) for r in mem.values()],
-                schema, names))
+            t0 = time.perf_counter()
+            with _obs.span("storage.memtable_shred", rows=len(mem)):
+                batches.append(ColumnBatch.from_rows(
+                    [({} if r is TOMBSTONE else r) for r in mem.values()],
+                    schema, names))
+            _MEM_SHRED_S.observe(time.perf_counter() - t0)
         for comp in view.components:   # newest first, as in _live_selection
             if comp.size == 0:
                 continue

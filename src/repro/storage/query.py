@@ -494,10 +494,23 @@ def run_query(plan, datasets: Dict[str, PartitionedDataset],
     ``mesh`` (a jax Mesh with a ``"part"`` axis, or an int device count)
     the columnar engine's per-partition loops run as single shard_map'ed
     SPMD dispatches over the partition mesh (``runtime/spmd``); results
-    are bit-identical to the loop, per the differential harness."""
-    if catalog is None:
-        catalog = _default_catalog(datasets)
-    phys = optimize(plan, catalog, config)
+    are bit-identical to the loop, per the differential harness.
+
+    The query runs in one ``obs.request`` context (its caller's, where
+    one is open), so every span it opens carries one ``trace_id``;
+    ``query.optimize`` times the rewrite beside the root ``exec.*``
+    span."""
+    with _obs.request():
+        if catalog is None:
+            catalog = _default_catalog(datasets)
+        with _obs.span("query.optimize"):
+            phys = optimize(plan, catalog, config)
+        return _execute(phys, datasets, vectorize, snapshot, mesh)
+
+
+def _execute(phys: PhysicalOp, datasets: Dict[str, PartitionedDataset],
+             vectorize: bool, snapshot: bool, mesh: Optional[Any]
+             ) -> Tuple[Rows, "Executor"]:
     pinned = []
     exec_datasets = datasets
     if snapshot:

@@ -11,10 +11,18 @@ forms the doc's tables use (``kernel.<kernel>.dispatches``,
 A new metric therefore fails this test until it is documented — the
 registry cannot silently drift from the code again (it previously lived
 in the ``obs/__init__`` docstring, where nothing checked it).
+
+The workload runs in a fresh interpreter: the registry is process-wide,
+so names that other test files register in the same worker would
+otherwise leak into the check.
 """
 
+import json
+import os
 import pathlib
 import re
+import subprocess
+import sys
 
 import pytest
 
@@ -25,7 +33,8 @@ from repro.core.lsm import TieredMergePolicy
 from repro.storage.dataset import PartitionedDataset
 from repro.storage.query import run_query
 
-DOC = pathlib.Path(__file__).resolve().parents[1] / "docs" / "METRICS.md"
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+DOC = ROOT / "docs" / "METRICS.md"
 
 # metric-name leaves that may follow a parametrized segment
 _KERNEL_LEAVES = ("dispatches", "pallas_dispatches", "h2d_bytes",
@@ -78,6 +87,8 @@ def _workload():
         run_query(plan, {"D": ds}, vectorize=True)
     for _ in range(2):
         run_query(plan, {"D": ds}, vectorize=True, mesh=1)
+    ds.crash_and_recover()                  # lsm.wal_* replay metrics
+    run_query(plan, {"D": ds}, vectorize=True)
 
     from benchmarks import feeds_bench
     feeds_bench.run(smoke=True)
@@ -102,11 +113,27 @@ def _workload():
         srv.stop()
 
 
-def test_every_emitted_metric_is_documented():
-    _workload()
+def _emitted_names(out: pathlib.Path) -> list:
+    """Every registry name after ``_workload()``, from a fresh
+    interpreter."""
+    code = ("import json, sys; import test_metrics_doc as m; m._workload(); "
+            "from repro import obs; "
+            "open(sys.argv[1], 'w').write(json.dumps(sorted(obs.snapshot())))")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(ROOT / "tests"), str(ROOT / "src"), str(ROOT)]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    proc = subprocess.run([sys.executable, "-c", code, str(out)], env=env,
+                          cwd=str(ROOT), capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return json.loads(out.read_text())
+
+
+def test_every_emitted_metric_is_documented(tmp_path):
     doc = DOC.read_text()
     documented = set(re.findall(r"`([a-z0-9_.<>]+)`", doc))
-    emitted = {_canon(n) for n in obs.snapshot()}
+    emitted = {_canon(n) for n in _emitted_names(tmp_path / "names.json")}
     missing = sorted(n for n in emitted if n not in documented)
     assert not missing, \
         f"metrics emitted but not documented in docs/METRICS.md: {missing}"
