@@ -58,6 +58,10 @@ __all__ = ["try_lower", "Unsupported"]
 # node() -> per-partition batches
 Node = Callable[[], List[ColumnBatch]]
 
+# fuzzy index chains run, and those that re-checked pred row by row
+_FUZZY_SELECTS = _obs.counter("fuzzy.selects")
+_FUZZY_RECHECKED = _obs.counter("fuzzy.selects_rechecked")
+
 
 class Unsupported(Exception):
     """This subplan stays on the row engine."""
@@ -564,8 +568,9 @@ def _compile_index_path(op: PhysicalOp, ex: Any,
         # verification uses the *spec's* gram length (the predicate's
         # semantics); the index's gram_length only shapes the candidate
         # postings.  Like every other access path, the full pred
-        # re-checks the gathered survivors unless the plan declared
-        # ``ranges_exact`` (pred may carry conjuncts beyond the spec).
+        # re-checks the gathered survivors unless the plan marked it
+        # ``ranges_exact`` (pred may carry conjuncts beyond the spec; the
+        # rewriter marks a pred that is the spec's own oracle).
         from ..fuzzy.ngram import spec_gram_length
         gram_k = spec_gram_length(fuzzy_spec)
     else:
@@ -696,6 +701,9 @@ def _compile_index_path(op: PhysicalOp, ex: Any,
         stat(search.kind, n_cand)
         if is_fuzzy:
             stat("T_OCCURRENCE", n_cand)
+            _FUZZY_SELECTS.inc()
+            if validate is not None and residual and pred is not None:
+                _FUZZY_RECHECKED.inc()
         stat("SORT_PK", n_cand)
         stat("PRIMARY_INDEX_LOOKUP", n_found)
         if validate is not None:

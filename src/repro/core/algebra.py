@@ -151,7 +151,11 @@ def select(child: LogicalOp, pred: Callable, *, fields: Sequence[str],
     predicates — ``ranges``, plus the fuzzy spec when present — fully
     capture ``pred``, letting the columnar engine skip the row-at-a-time
     residual re-check (and fuse filter+aggregate into one kernel
-    pass)."""
+    pass).  A fuzzy select that declares no ``ranges`` and passes its
+    spec's own oracle, ``pred=fuzzy_predicate(spec), fuzzy=spec``, gets
+    its fuzzy conjunct treated as exact without asking: the batched
+    verify decides that pred exactly.  Wrapping the oracle (an extra
+    conjunct, a lambda) opts out and keeps the re-check."""
     return LogicalOp("SELECT", (child,),
                      {"pred": pred, "fields": tuple(fields),
                       "ranges": dict(ranges or {}), "spatial": spatial,

@@ -113,6 +113,17 @@ def _visible_columns(op: LogicalOp) -> Optional[set]:
 # Logical -> physical translation with R2-R5 inline
 # ---------------------------------------------------------------------------
 
+def _pred_is_fuzzy_oracle(attrs: Dict[str, Any]) -> bool:
+    """A fuzzy select whose ``pred`` is its own spec's oracle
+    (``fuzzy_predicate(spec)``) and that declares no ranges is decided
+    exactly by the batched verify: its fuzzy conjunct is exact, and the
+    row-at-a-time re-check would repeat the verify's answer."""
+    from ..fuzzy.ngram import FuzzyPredicate
+    pred = attrs["pred"]
+    return (isinstance(pred, FuzzyPredicate) and not attrs.get("ranges")
+            and pred.decides(attrs["fuzzy"]))
+
+
 def _to_physical(op: LogicalOp, cat: Catalog, cfg: RewriteConfig) -> PhysicalOp:
     k = op.kind
 
@@ -162,7 +173,8 @@ def _to_physical(op: LogicalOp, cat: Catalog, cfg: RewriteConfig) -> PhysicalOp:
                          "fields": op.attrs["fields"],
                          "ranges": op.attrs.get("ranges", {}),
                          "ranges_exact": bool(op.attrs.get("ranges_exact",
-                                                           False)),
+                                                           False))
+                         or _pred_is_fuzzy_oracle(op.attrs),
                          "fuzzy": fz, "gram_length": ix.gram_length},
                         lookup.delivered)
             # rtree (paper Q5) and keyword (paper Q6) access paths share the

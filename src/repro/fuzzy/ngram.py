@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Sequence, Tuple
+from typing import Any, Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -48,7 +48,8 @@ from ..core.functions import (edit_distance_check, gram_tokens,
 from ..kernels.fuzzy_ops import fnv1a_hash
 
 __all__ = ["GRAM_K", "GramPostings", "FuzzySpec", "spec_gram_length",
-           "value_gram_hashes", "query_grams", "fuzzy_predicate"]
+           "value_gram_hashes", "query_grams", "FuzzyPredicate",
+           "fuzzy_predicate"]
 
 GRAM_K = 3                      # default gram length (AsterixDB's ngram(3))
 
@@ -246,19 +247,54 @@ def query_grams(spec: FuzzySpec, index_k: int) -> Tuple[np.ndarray, int]:
     raise ValueError(f"unknown fuzzy predicate kind {kind!r}")
 
 
-def fuzzy_predicate(spec: FuzzySpec) -> Callable:
+class FuzzyPredicate:
+    """The row-engine oracle of one fuzzy spec, carrying the spec it
+    decides in normal form (``spec``: field, kind, target, param as a
+    float, gram length), so a planner can tell the oracle of the
+    select's own spec from any other predicate.  Non-string / absent
+    values never match."""
+
+    __slots__ = ("spec", "_target_grams")
+
+    def __init__(self, spec: FuzzySpec):
+        self.spec = _normal_spec(spec)
+        _fld, kind, target, _param, k = self.spec
+        if kind not in ("ed", "jaccard"):
+            raise ValueError(f"unknown fuzzy predicate kind {kind!r}")
+        self._target_grams = gram_tokens(target, k) \
+            if kind == "jaccard" else None
+
+    def __call__(self, r: Dict[str, Any]) -> bool:
+        fld, kind, target, param, k = self.spec
+        v = r.get(fld)
+        if not isinstance(v, str):
+            return False
+        if kind == "ed":
+            return edit_distance_check(v, target, param)
+        return similarity_jaccard_check(gram_tokens(v, k),
+                                        self._target_grams, param)
+
+    def decides(self, spec: FuzzySpec) -> bool:
+        """Is this the oracle of ``spec`` (compared in normal form)?"""
+        return self.spec == _normal_spec(spec)
+
+
+def _normal_spec(spec: FuzzySpec) -> Tuple[str, str, str, float, int]:
+    """``spec`` as (field, kind, target, float param, gram length): the
+    int and float forms of a param, and a Jaccard spec with or without
+    its default gram length, compare equal."""
+    fld, kind, target, param = spec[:4]
+    return (fld, kind, target, float(param), spec_gram_length(spec))
+
+
+def fuzzy_predicate(spec: FuzzySpec) -> FuzzyPredicate:
     """The row-engine oracle for a fuzzy spec — exactly the predicate the
     batched verification kernels reproduce, so plans can pass
     ``pred=fuzzy_predicate(spec), fuzzy=spec`` and both engines agree.
+    The rewriter recognises that pairing (``FuzzyPredicate.decides``)
+    and marks the fuzzy conjunct exact: the columnar chain keeps the
+    batched verify's answer and re-checks no row.  A pred that wraps
+    the oracle (an extra conjunct, a lambda) keeps the row re-check.
     Jaccard gram length comes from the spec (5th element, default
     GRAM_K).  Non-string / absent values never match."""
-    fld, kind, target, param = spec[:4]
-    if kind == "ed":
-        return lambda r: isinstance(r.get(fld), str) \
-            and edit_distance_check(r[fld], target, param)
-    if kind == "jaccard":
-        k = spec_gram_length(spec)
-        tg = gram_tokens(target, k)
-        return lambda r: isinstance(r.get(fld), str) \
-            and similarity_jaccard_check(gram_tokens(r[fld], k), tg, param)
-    raise ValueError(f"unknown fuzzy predicate kind {kind!r}")
+    return FuzzyPredicate(spec)

@@ -20,6 +20,7 @@ divides exact integer counts in float64 — the same arithmetic as
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, List, Sequence, Set, Tuple
 
 import numpy as np
@@ -51,8 +52,8 @@ def verify_values(values: Sequence[str], spec: FuzzySpec, k: int
         return np.zeros(0, dtype=bool)
     _fld, kind, target, param = spec[:4]
     if kind == "ed":
-        return np.asarray(F.edit_distances(values, target, int(param))
-                          <= int(param))
+        d = math.floor(param)       # ed <= param, for any real param
+        return np.asarray(F.edit_distances(values, target, d) <= d)
     return _jaccard_values(values, target, float(param), k)
 
 
@@ -82,7 +83,7 @@ def verify_mask(batch: Any, mask: np.ndarray, spec: FuzzySpec, k: int
         if spec[1] == "ed" and used.shape[0] > F.HOST_ED_MAX:
             # many candidates: one pass over the column's resident
             # dictionary (only the query's characters ship)
-            d = int(spec[3])
+            d = math.floor(spec[3])
             dist = F.dictionary_edit_distances(col, vals, spec[2], d)
             ok_used = dist[used] <= d
         else:

@@ -216,8 +216,10 @@ def test_differential_fuzzy(seed, n_rows, parts, threshold, kind):
     the same flush/merge/recover lifecycles as every other index path
     (_build interleaves them), including memtable-resident rows, deletes,
     open-type drift, and late index creation (component backfill).
-    Variants cover exact specs (kernel-only verify), preds carrying an
-    extra non-fuzzy conjunct (residual re-check must run), and jaccard
+    Variants cover the spec's own oracle (kernel-only verify, whether
+    the plan declares ``ranges_exact`` or the rewriter infers it), preds
+    carrying an extra non-fuzzy conjunct (residual re-check must run),
+    and jaccard
     specs whose gram length differs from the index's (no pruning, shared
     verify)."""
     from repro.fuzzy import fuzzy_predicate

@@ -18,8 +18,12 @@ from repro.core.functions import (edit_distance, edit_distance_check,
                                   gram_tokens, similarity_jaccard)
 from repro.core.lsm import LSMIndex, TieredMergePolicy
 from repro.data.dedup import FuzzyJoin, _token_hash, _token_hashes
-from repro.fuzzy import (GramPostings, fuzzy_predicate, query_grams,
-                         value_gram_hashes, verify_values)
+from repro import obs
+from repro.columnar.batch import ColumnBatch
+from repro.fuzzy import (GRAM_K, GramPostings, fuzzy_predicate, query_grams,
+                         spec_gram_length, value_gram_hashes, verify_mask,
+                         verify_values)
+from repro.fuzzy import ngram
 from repro.kernels import fuzzy_ops as F
 from repro.storage.dataset import PartitionedDataset
 from repro.storage.query import run_query
@@ -353,6 +357,136 @@ def test_fuzzy_select_pred_with_extra_conjunct_not_dropped():
     assert all(r["id"] % 2 == 0 for r in rows_c)
     assert ex.stats.rows_fallback == 0
     assert ex.stats.rows_fuzzy_vectorized > 0
+
+
+def _count_oracle_calls(monkeypatch):
+    """Count the scalar checks the fuzzy oracle makes (one per row it
+    decides)."""
+    calls = []
+    for name in ("edit_distance_check", "similarity_jaccard_check"):
+        real = getattr(ngram, name)
+        monkeypatch.setattr(
+            ngram, name,
+            lambda *a, _real=real: calls.append(a) or _real(*a))
+    return calls
+
+
+def _fuzzy_counts():
+    snap = obs.snapshot()
+    return (snap.get("fuzzy.selects", 0),
+            snap.get("fuzzy.selects_rechecked", 0))
+
+
+@pytest.mark.parametrize("pred_spec,fuzzy_spec", [
+    (("w", "ed", "tonight", 2), ("w", "ed", "tonight", 2)),
+    (("w", "ed", "tonight", 2.0), ("w", "ed", "tonight", 2.0)),
+    (("w", "ed", "tonight", 2), ("w", "ed", "tonight", 2.0)),
+    (("w", "jaccard", "tonight", 0.5), ("w", "jaccard", "tonight", 0.5)),
+    (("w", "jaccard", "tonight", 0.5), ("w", "jaccard", "tonight", 0.5, 3)),
+    (("w", "jaccard", "tonite", 1), ("w", "jaccard", "tonite", 1.0)),
+])
+def test_fuzzy_select_own_oracle_skips_row_recheck(pred_spec, fuzzy_spec,
+                                                   monkeypatch):
+    """``pred=fuzzy_predicate(spec), fuzzy=spec`` (the spec compared in
+    normal form): the batched verify's answer stands, no row goes
+    through pred, and the answer is the oracle's."""
+    rng = random.Random(sum(map(ord, repr(fuzzy_spec))))
+    ds = _fuzzy_ds(rng)
+    plan = A.select(A.scan("D"), pred=fuzzy_predicate(pred_spec),
+                    fields=["w"], fuzzy=fuzzy_spec)
+    calls = _count_oracle_calls(monkeypatch)
+    before = _fuzzy_counts()
+    rows_c, ex = run_query(plan, {"D": ds}, vectorize=True)
+    after = _fuzzy_counts()
+    assert calls == []
+    assert (after[0] - before[0], after[1] - before[1]) == (1, 0)
+    assert ex.stats.rows_fallback == 0
+    assert ex.stats.rows_fuzzy_vectorized > 0
+    rows_r, _ = run_query(plan, {"D": ds})
+    oracle = [r for r in ds.scan() if fuzzy_predicate(pred_spec)(r)]
+    assert rows_c
+    assert _canon(rows_c) == _canon(rows_r) == _canon(oracle)
+
+
+@pytest.mark.parametrize("case", ["extra_conjunct", "other_spec", "ranges"])
+def test_fuzzy_select_other_pred_keeps_row_recheck(case):
+    """Any pred but the select's own oracle keeps the row re-check: the
+    oracle wrapped with an extra conjunct, the oracle of another spec
+    than ``fuzzy=``, and the oracle beside declared ranges."""
+    rng = random.Random(5)
+    ds = _fuzzy_ds(rng, n=150)
+    spec = ("w", "ed", "tonight", 2)
+    fz = fuzzy_predicate(spec)
+    kw = {}
+    if case == "extra_conjunct":
+        pred = lambda r: fz(r) and r["id"] % 2 == 0
+    elif case == "other_spec":
+        pred = fuzzy_predicate(("w", "ed", "tonight", 1))
+    else:
+        pred = fz                   # every id lies in the declared range
+        kw["ranges"] = {"id": (0, 10 ** 6)}
+    plan = A.select(A.scan("D"), pred=pred, fields=["w", "id"],
+                    fuzzy=spec, **kw)
+    before = _fuzzy_counts()
+    rows_c, ex = run_query(plan, {"D": ds}, vectorize=True)
+    after = _fuzzy_counts()
+    assert (after[0] - before[0], after[1] - before[1]) == (1, 1)
+    assert ex.stats.rows_fallback == 0
+    rows_r, _ = run_query(plan, {"D": ds})
+    oracle = [r for r in ds.scan() if pred(r)]
+    assert rows_c
+    assert _canon(rows_c) == _canon(rows_r) == _canon(oracle)
+
+
+def test_fuzzy_predicate_normal_form():
+    assert fuzzy_predicate(("w", "ed", "x", 2)).spec \
+        == fuzzy_predicate(("w", "ed", "x", 2.0)).spec
+    j = fuzzy_predicate(("w", "jaccard", "x", 0.5))
+    assert j.decides(("w", "jaccard", "x", 0.5, GRAM_K))
+    assert not j.decides(("w", "jaccard", "x", 0.5, 2))
+    assert not j.decides(("w", "jaccard", "x", 0.4))
+    assert not j.decides(("v", "jaccard", "x", 0.5))
+    assert not fuzzy_predicate(("w", "ed", "x", 2)).decides(
+        ("w", "ed", "y", 2))
+    with pytest.raises(ValueError):
+        fuzzy_predicate(("w", "soundex", "x", 1))
+
+
+# Edge values for the batched verify, on which the skipped re-check now
+# rests: the empty string, non-ASCII, lengths d and d + 1 away from the
+# target, None and non-strings in an ``obj`` column.
+_EDGE_VALUES = ["", "t", "to", "tonight", "tönight", "tonigh", "tonig",
+                "toni", "tonightxy", "tonightxyz", "café", "東京タワー",
+                "TONIGHT", "tøñîght", "nightto", "ton ight"]
+
+
+@pytest.mark.parametrize("target", ["tonight", "tönight", ""])
+@pytest.mark.parametrize("spec_tail", [("ed", 0), ("ed", 1), ("ed", 2),
+                                       ("ed", 2.5), ("ed", -0.5),
+                                       ("jaccard", 0.5)])
+@pytest.mark.parametrize("many", [False, True],
+                         ids=["host_dp", "dictionary_dp"])
+@pytest.mark.parametrize("kind", ["str", "obj"])
+def test_verify_mask_matches_oracle_on_edge_values(target, spec_tail, many,
+                                                   kind):
+    rng = random.Random(7)
+    vals = list(_EDGE_VALUES)
+    if many:                        # above the host DP's batch floor
+        vals += [_rng_word(rng, 12) + str(i)
+                 for i in range(F.HOST_ED_MAX + 8)]
+    rows = [{"id": i, "w": v} for i, v in enumerate(vals)]
+    if kind == "obj":
+        rows += [{"id": -1, "w": None}, {"id": -2, "w": 7},
+                 {"id": -3, "w": 2.5}, {"id": -4}, {"id": -5, "w": ["t"]}]
+    batch = ColumnBatch.from_rows(rows)
+    assert batch.columns["w"].kind == kind
+    spec = ("w",) + spec_tail[:1] + (target,) + spec_tail[1:]
+    oracle = fuzzy_predicate(spec)
+    want = np.asarray([oracle(r) for r in rows])
+    mask = np.ones(len(rows), dtype=bool)
+    mask[1::5] = False              # some rows are not candidates
+    got = verify_mask(batch, mask, spec, spec_gram_length(spec))
+    assert got.tolist() == (want & mask).tolist()
 
 
 def test_jaccard_spec_gram_length_differs_from_index():

@@ -76,6 +76,9 @@ def test_repeated_chain_hits_pool_and_ships_nothing():
     assert ex1.stats.rows_fallback == 0
     assert ex1.stats.plan_cache_misses >= 1       # first sighting compiles
     assert ex1.stats.h2d_bytes > 0                # cold: operands upload
+    # dead datasets of earlier tests sit in reference cycles: collect
+    # them now, or their finalizers evict inside the window below
+    gc.collect()
     r1 = DP.pool.resident_bytes()
     assert r1 > 0
     s0 = DP.pool.stats()
@@ -149,6 +152,7 @@ def test_merge_retirement_frees_buffers_after_unpin():
     run_query(_select_plan(), {"D": ds}, vectorize=True)
     _, ex = run_query(_select_plan(), {"D": ds}, vectorize=True)
     assert ex.stats.h2d_bytes == 0                # warm before the merge
+    gc.collect()                # as above: earlier tests' dead buffers
     r1 = DP.pool.resident_bytes()
     assert r1 > 0
     prim = ds.partitions[0].primary

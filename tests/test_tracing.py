@@ -418,6 +418,18 @@ def test_ingest_share_readers(name, key):
     assert read(_cell()) is None
 
 
+def test_fuzzy_recheck_share_reader():
+    read = _reader("fuzzy_recheck_share.reads")
+    c = _cell(obs0={"fuzzy.selects": 10, "fuzzy.selects_rechecked": 10},
+              obs1={"fuzzy.selects": 42, "fuzzy.selects_rechecked": 18})
+    assert read(c) == pytest.approx(25.0)     # 8 of 32 chains re-checked
+    assert read(_cell(obs1={"fuzzy.selects": 4})) == 0.0
+    # no fuzzy chain in the window, or a program without the counters
+    assert read(_cell(obs0={"fuzzy.selects": 3},
+                      obs1={"fuzzy.selects": 3})) is None
+    assert read(_cell()) is None
+
+
 def test_wal_replay_share_reads_the_registry_after_recovery(monkeypatch):
     _check_recovery_share(monkeypatch, "wal_replay_share.recovery",
                           "lsm.wal_replay_seconds")
