@@ -14,9 +14,24 @@ the same window under the JAX profiler and the program's span tracer and
 reports the per-layer metrics (``bench/metrics/<name>.py``) in place of
 the end-to-end ones.
 
+A configuration may name ``mesh``: the number of chips its partitions
+span, the deployment's cluster layout mapped onto the cell's 1 or 4
+chips.  Without it the run builds no mesh and every query runs the
+program's per-partition loop.  With it (an integer of at least 2 that
+divides ``partitions`` and is at most the cell's ``chips``) the run
+builds one ``repro.runtime.spmd.partition_mesh`` and hands it to every
+program query (warm-up, clients, feed poller, recovery probe) as
+``run_query(..., mesh=...)``; the program's SPMD paths do the rest.
+Each result reports under ``info["mesh"]`` the mesh's size and the
+window's ``mesh.*`` counter deltas (SPMD dispatches, partitions they
+covered, fallbacks to the loop).  The trace reduction
+(``bench/trace.py``) already averages device busy time over every chip
+in the trace, that is over the cell's chips.
+
 It refuses to run (exit 2, no result) unless JAX's first device is a
-TPU and there are as many as the cell asks for.  JAX's persistent
-compilation cache lives in ``<checkout>/.jax_cache``.
+TPU and there are as many as the cell asks for, and before any set-up
+where the configuration's ``mesh`` is not such a value.  JAX's
+persistent compilation cache lives in ``<checkout>/.jax_cache``.
 """
 
 from __future__ import annotations
@@ -48,6 +63,23 @@ def load_spec() -> Dict[str, Any]:
 
 def load_config(name: str) -> Dict[str, Any]:
     return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+
+
+def mesh_devices(cfg: Dict[str, Any], cell: Dict[str, Any]) -> int:
+    """The chips the deployment's partitions span: 0 (no mesh) where the
+    configuration has no ``mesh``; otherwise its ``mesh``, which must be
+    an integer of at least 2 that divides ``partitions`` and is at most
+    the cell's ``chips``."""
+    if "mesh" not in cfg:
+        return 0
+    n, parts, chips = cfg["mesh"], int(cfg["partitions"]), int(cell["chips"])
+    if type(n) is not int or n < 2 or parts % n or n > chips:
+        raise ValueError(
+            f"bench: configuration {cell['config']!r}: "
+            f"'mesh' must be an integer >= 2 that divides partitions "
+            f"({parts}) and is at most the cell's chips ({chips}), "
+            f"not {n!r}")
+    return n
 
 
 def load_reader(name: str) -> Callable[[Any], Optional[float]]:
@@ -162,6 +194,11 @@ class Cell:
         self.cell = cell
         self.cfg = load_config(cell["config"])
         self.cfg.update(overrides or {})
+        self.mesh_devices = mesh_devices(self.cfg, cell)
+        self.mesh = None
+        if self.mesh_devices:
+            from repro.runtime.spmd import partition_mesh
+            self.mesh = partition_mesh(self.mesh_devices)
         self.mix = traffic.load_mix(cell["traffic"])
         self.seed = int(seed)
         self.seconds = float(seconds)
@@ -181,7 +218,7 @@ class Cell:
         try:
             with jax.profiler.TraceAnnotation(f"bench.{r.req['template']}"):
                 rows, ex = run_query(plan, datasets, vectorize=True,
-                                     snapshot=True)
+                                     snapshot=True, mesh=self.mesh)
             r.fallback = bool(ex.stats.fallback_reasons)
             r.traces = ex.stats.kernel_retraces
             if r.req["check"]:
@@ -282,6 +319,11 @@ class Cell:
         c1 = meter.snapshot()
         self.info["window_compiles"] = c1[0] - c0[0] + c1[1] - c0[1]
         self.info["window_compile_s"] = round(c1[2] - c0[2], 6)
+        self.info["mesh"] = {"devices": self.mesh_devices}
+        for k in ("spmd_dispatches", "spmd_partitions", "spmd_fallbacks"):
+            key = f"mesh.{k}"
+            self.info["mesh"][k] = (self.obs1.get(key, 0)
+                                    - self.obs0.get(key, 0))
         self.spans = []
         self.trace_red = None
         if self.trace:
@@ -641,6 +683,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                  if w["name"] == args.workload), None)
     if cell is None:
         log(f"bench: no workload {args.workload!r} in BENCHMARK.json")
+        return 2
+    try:
+        mesh_devices(load_config(cell["config"]), cell)
+    except ValueError as e:
+        log(str(e))
         return 2
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
     import jax
