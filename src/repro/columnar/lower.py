@@ -638,6 +638,13 @@ def _compile_index_path(op: PhysicalOp, ex: Any,
         # dispatch over the active mesh (plancache.run_all); a None
         # return or per-partition None entries keep the loop below
         spmd_res = fused.run_all(cols) if fused is not None else None
+        # SPMD: every partition's T-occurrence as one stacked dispatch
+        # (None without a mesh: the loop below counts per partition)
+        fz_masks = None
+        if is_fuzzy:
+            with _obs.span("fuzzy.candidates"):
+                fz_masks = ds.ngram_candidate_masks(search.attrs["field"],
+                                                    fuzzy_spec)
         for i in range(ds.num_partitions):
             if spmd_res is not None:
                 res = spmd_res[i]      # None: legacy path, same as loop
@@ -653,9 +660,12 @@ def _compile_index_path(op: PhysicalOp, ex: Any,
             if is_fuzzy:
                 # T-occurrence candidate bitmap, already position-aligned
                 # with the partition's scan batch — no PK intersection
-                with _obs.span("fuzzy.candidates"):
-                    mask = ds.ngram_candidate_mask(i, search.attrs["field"],
-                                                   fuzzy_spec)
+                if fz_masks is not None:
+                    mask = fz_masks[i]
+                else:
+                    with _obs.span("fuzzy.candidates"):
+                        mask = ds.ngram_candidate_mask(
+                            i, search.attrs["field"], fuzzy_spec)
                 n_cand += int(mask.sum())
                 if not mask.any():
                     emit_empty()                 # no candidates

@@ -26,7 +26,9 @@ itself is the compiled artifact, so a hit is purely a dictionary probe.
 While tracing is enabled, each partition's dispatch shows its host and
 device halves as spans: ``chain.operands`` (the gather), ``chain.h2d``
 (the pool fetch), ``chain.device`` (the core through its
-``device_get``) and ``chain.assemble`` (the host-side result).
+``device_get``) and ``chain.assemble`` (the host-side result); under a
+partition mesh ``chain.stack`` times the stacking of every partition's
+operands before the one dispatch.
 
 Declines are cheap and total: any input the fused core cannot represent
 exactly (unordered key dictionary, obj-degraded validate column, fuzzy
@@ -456,59 +458,59 @@ def compile_chain(ds: Any, *, chain_ops: Tuple[str, ...], search_field: str,
                spmd.mesh_key(mesh), rows, "spmd")
         plan_cache.note(key)
 
-        sc = spmd.stack_cache
-        st_tiers = []
-        st_bounds = []
-        for f in range(n_fields):
-            fp, fb = [], []
-            for t in range(n_tiers[f]):
-                arrs = [g["tiers"][f][t] if t < len(g["tiers"][f]) else None
-                        for _i, g in entries]
-                dt = next(a.dtype for a in arrs if a is not None)
-                fp.append(sc.stack(arrs, rows, tier_w[f][t], dt))
-                a_v = np.zeros(rows, np.int64)
-                b_v = np.zeros(rows, np.int64)
-                o_v = np.zeros(rows, np.int64)
-                for r, (_i, g) in enumerate(entries):
-                    if t < len(g["bounds"][f]):
-                        a, b, off = g["bounds"][f][t]
-                        a_v[r], b_v[r], o_v[r] = a, b, off
-                fb.append((a_v, b_v, o_v))
-            st_tiers.append(tuple(fp))
-            st_bounds.append(tuple(fb))
-        idx_st = sc.stack([g["idx_pad"] for _i, g in entries], rows,
-                          live_p2, g0["idx_pad"].dtype)
-        n_live_v = np.zeros(rows, np.int64)
-        for r, (_i, g) in enumerate(entries):
-            n_live_v[r] = g["n_live"]
-        st_preds = []
-        for j in range(len(pred_sig)):
-            d0 = g0["preds"][j][0]
-            dd = sc.stack([g["preds"][j][0] for _i, g in entries], rows,
-                          live_p2, d0.dtype)
-            vv = sc.stack([g["preds"][j][1] for _i, g in entries], rows,
-                          live_p2, np.bool_, fill=False)
-            lo_v = np.zeros(rows, d0.dtype)
-            hi_v = np.zeros(rows, d0.dtype)
+        with obs.span("chain.stack"):
+            sc = spmd.stack_cache
+            st_tiers = []
+            st_bounds = []
+            for f in range(n_fields):
+                fp, fb = [], []
+                for t in range(n_tiers[f]):
+                    arrs = [g["tiers"][f][t] if t < len(g["tiers"][f])
+                            else None for _i, g in entries]
+                    dt = next(a.dtype for a in arrs if a is not None)
+                    fp.append(sc.stack(arrs, rows, tier_w[f][t], dt))
+                    a_v = np.zeros(rows, np.int64)
+                    b_v = np.zeros(rows, np.int64)
+                    o_v = np.zeros(rows, np.int64)
+                    for r, (_i, g) in enumerate(entries):
+                        if t < len(g["bounds"][f]):
+                            a, b, off = g["bounds"][f][t]
+                            a_v[r], b_v[r], o_v[r] = a, b, off
+                    fb.append((a_v, b_v, o_v))
+                st_tiers.append(tuple(fp))
+                st_bounds.append(tuple(fb))
+            idx_st = sc.stack([g["idx_pad"] for _i, g in entries], rows,
+                              live_p2, g0["idx_pad"].dtype)
+            n_live_v = np.zeros(rows, np.int64)
             for r, (_i, g) in enumerate(entries):
-                _d, _v, lo, hi = g["preds"][j]
-                blo, bhi = _prep_pred_bounds(_d, lo, hi)
-                lo_v[r], hi_v[r] = blo, bhi
-            st_preds.append((dd, vv, lo_v, hi_v))
-        st_aggs = []
-        for j in range(len(agg_sig)):
-            d0 = g0["agg_arrays"][j][0]
-            dd = sc.stack([g["agg_arrays"][j][0] for _i, g in entries],
-                          rows, live_p2, d0.dtype)
-            vv = sc.stack([g["agg_arrays"][j][1] for _i, g in entries],
-                          rows, live_p2, np.bool_, fill=False)
-            st_aggs.append((dd, vv))
-        with obs.span("chain.device"):
-            n_cand_a, n_found_a, n_valid_a, mask_a, per_col_a = \
-                spmd.run_chain_stack(mesh, tuple(st_tiers),
-                                     tuple(st_bounds), idx_st, n_live_v,
-                                     tuple(st_preds), tuple(st_aggs),
-                                     total_p2, live_p2, len(entries))
+                n_live_v[r] = g["n_live"]
+            st_preds = []
+            for j in range(len(pred_sig)):
+                d0 = g0["preds"][j][0]
+                dd = sc.stack([g["preds"][j][0] for _i, g in entries], rows,
+                              live_p2, d0.dtype)
+                vv = sc.stack([g["preds"][j][1] for _i, g in entries], rows,
+                              live_p2, np.bool_, fill=False)
+                lo_v = np.zeros(rows, d0.dtype)
+                hi_v = np.zeros(rows, d0.dtype)
+                for r, (_i, g) in enumerate(entries):
+                    _d, _v, lo, hi = g["preds"][j]
+                    blo, bhi = _prep_pred_bounds(_d, lo, hi)
+                    lo_v[r], hi_v[r] = blo, bhi
+                st_preds.append((dd, vv, lo_v, hi_v))
+            st_aggs = []
+            for j in range(len(agg_sig)):
+                d0 = g0["agg_arrays"][j][0]
+                dd = sc.stack([g["agg_arrays"][j][0] for _i, g in entries],
+                              rows, live_p2, d0.dtype)
+                vv = sc.stack([g["agg_arrays"][j][1] for _i, g in entries],
+                              rows, live_p2, np.bool_, fill=False)
+                st_aggs.append((dd, vv))
+        n_cand_a, n_found_a, n_valid_a, mask_a, per_col_a = \
+            spmd.run_chain_stack(mesh, tuple(st_tiers), tuple(st_bounds),
+                                 idx_st, n_live_v, tuple(st_preds),
+                                 tuple(st_aggs), total_p2, live_p2,
+                                 len(entries))
         out: List[Optional[ChainResult]] = [None] * P
         for r, (i, g) in enumerate(entries):
             per_col = [tuple(x[r] for x in pc) for pc in per_col_a]

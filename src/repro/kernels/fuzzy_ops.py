@@ -92,21 +92,28 @@ def fnv1a_hash(tokens: Sequence[str]) -> np.ndarray:
 # T-occurrence segmented count (ngram candidate generation)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnums=(6,))
-def _tocc_tier_core(cnt, pos, starts, cum, nhits, off, hits_p2):
+def _tocc_tier_math(cnt, pos, starts, cum, nhits, off, hits_p2):
     """Add one storage tier's query-gram hits into the running count.
     Hit ``k`` (of ``nhits``) lies in segment ``g`` — the last whose
     exclusive length prefix ``cum[g]`` is <= k — at ``starts[g] + k -
     cum[g]`` of the tier's resident positions; it scatter-adds at its row
     ``+ off`` of the storage concat.  Lanes past ``nhits`` (pow2 padding)
-    land in the sentinel slot, the count's last."""
-    _traced()
+    land in the sentinel slot, the count's last.  Jit-agnostic: traced by
+    :func:`_tocc_tier_core` (one tier a dispatch) and by
+    ``runtime/spmd._tocc_fn`` (one tier slot of every partition of a
+    shard, flattened into one count)."""
     k = jnp.arange(hits_p2, dtype=jnp.int64)
     g = jnp.searchsorted(cum, k, side="right") - 1
     live = k < nhits
     idx = jnp.where(live, starts[g] + k - cum[g], 0)
     sentinel = cnt.shape[0] - 1
     return cnt.at[jnp.where(live, pos[idx] + off, sentinel)].add(1)
+
+
+@functools.partial(jax.jit, static_argnums=(6,))
+def _tocc_tier_core(cnt, pos, starts, cum, nhits, off, hits_p2):
+    _traced()
+    return _tocc_tier_math(cnt, pos, starts, cum, nhits, off, hits_p2)
 
 
 @jax.jit
@@ -128,7 +135,8 @@ def ngram_t_occurrence(tiers: Sequence[Any], total: int,
     hit segments (work O(hits), pow2-padded) into one device-resident
     count, so programs are keyed by pow2 buckets, not by the tier list.
     Below the jax dispatch floor the segments gather and count on the
-    host."""
+    host.  Under a partition mesh ``runtime/spmd.batched_t_occurrence``
+    counts every partition's tiers in one dispatch with the same math."""
     hits = [int((en - st).sum()) for _o, _p, _pp, st, en in tiers]
     if sum(hits) == 0:
         return np.zeros(total, dtype=bool)

@@ -27,12 +27,20 @@ Span naming convention (``obs.span(name, **attrs)``):
   chain.operands          one partition's fused-chain operand gather
                           (tier probes, scan batch, range predicates,
                           pow2 padding; columnar/plancache)
-  chain.h2d               the device-pool fetch of those operands
+  chain.stack             under a partition mesh, the stacking of every
+                          partition's chain operands before the one
+                          dispatch (runtime/spmd.StackCache)
+  chain.h2d               the device-pool fetch of those operands (one
+                          sharded fetch a query on the mesh)
   chain.device            the chain core's dispatch through its
                           ``device_get``: the host waits on the device
   chain.assemble          the chain result's host assembly
   fuzzy.candidates        ngram T-occurrence candidate bitmap of one
-                          partition (legacy per-partition chain path)
+                          partition (legacy per-partition chain path), or
+                          of every partition at once under a mesh
+  fuzzy.tocc              the T-occurrence count inside it: one per
+                          partition on the loop, one per query stacked
+                          on the mesh
   fuzzy.verify            batched similarity verification of one
                           partition's candidates
   lsm.flush               one memtable flush (attrs: rows, bytes)

@@ -40,6 +40,16 @@ Metrics (docs/METRICS.md §mesh):
                                 device exchange (connector repartition)
   mesh.shard<k>.h2d_bytes       counter: per-shard share of sharded
                                 uploads (``fetch_sharded``)
+  mesh.stack_hits               counter: ``StackCache`` lookups that found
+                                the stacked array (a warm query)
+  mesh.stack_misses             counter: lookups that built a new stack
+  mesh.stack_bytes              counter: bytes of the stacks built
+  mesh.tocc_dispatches          counter: stacked T-occurrence dispatches
+                                (one a fuzzy index chain)
+  mesh.tocc_hit_positions       counter: posting positions those
+                                dispatches gathered (live hit lanes)
+  mesh.tocc_count_cells         counter: int32 count cells they wrote
+                                (one count row per stacked partition)
 """
 
 from __future__ import annotations
@@ -66,7 +76,8 @@ __all__ = [
     "PART_AXIS", "partition_mesh", "use_partition_mesh", "active_mesh",
     "mesh_key", "dispatch_totals", "StackCache", "stack_cache",
     "fetch_sharded", "batched_range_masks", "batched_select_aggregate",
-    "exchange_batches", "psum_merge", "pmin_merge", "pmax_merge",
+    "batched_t_occurrence", "exchange_batches", "psum_merge",
+    "pmin_merge", "pmax_merge",
 ]
 
 PART_AXIS = "part"
@@ -76,6 +87,12 @@ _DISPATCHES = obs.counter("mesh.spmd_dispatches")
 _PARTITIONS = obs.counter("mesh.spmd_partitions")
 _FALLBACKS = obs.counter("mesh.spmd_fallbacks")
 _EXCH_ROWS = obs.counter("mesh.exchange_rows")
+_STACK_HITS = obs.counter("mesh.stack_hits")
+_STACK_MISSES = obs.counter("mesh.stack_misses")
+_STACK_BYTES = obs.counter("mesh.stack_bytes")
+_TOCC_DISPATCHES = obs.counter("mesh.tocc_dispatches")
+_TOCC_HITS = obs.counter("mesh.tocc_hit_positions")
+_TOCC_CELLS = obs.counter("mesh.tocc_count_cells")
 
 
 # ---------------------------------------------------------------------------
@@ -193,11 +210,14 @@ class StackCache:
             e = self._entries.get(key)
             if e is not None and all(
                     r() is a for r, a in zip(e[0], arrs) if r is not None):
+                _STACK_HITS.inc()
                 return e[1]
         out = np.full((rows, width), fill, dtype=dtype)
         for i, a in enumerate(arrs):
             if a is not None and a.shape[0]:
                 out[i, :a.shape[0]] = a
+        _STACK_MISSES.inc()
+        _STACK_BYTES.inc(int(out.nbytes))
         refs = tuple(None if a is None else weakref.ref(a) for a in arrs)
         fins = []
         for a in arrs:
@@ -270,11 +290,12 @@ def _chain_fn(mesh: Mesh, tiers_struct: Tuple[int, ...], n_preds: int,
               n_aggs: int, total_p2: int, live_p2: int):
     """jit(shard_map(vmap(chain math))) for one chain structure: the
     same fused Figure-6 math as ``plancache._chain_core``, run on every
-    partition lane of the local shard."""
+    partition lane of the local shard (the program ``jit__chain_stack``
+    on a device trace)."""
     from ..columnar.plancache import _chain_math
     tr = _traces()
 
-    def body(tiers, bounds, idx_pad, n_live, preds, aggds):
+    def _chain_stack(tiers, bounds, idx_pad, n_live, preds, aggds):
         tr["n"] += 1
         _record_retrace()
 
@@ -283,7 +304,7 @@ def _chain_fn(mesh: Mesh, tiers_struct: Tuple[int, ...], n_preds: int,
             return _chain_math(t, b, ix, nl, pr, ag, total_p2, live_p2)
         return jax.vmap(one)((tiers, bounds, idx_pad, n_live, preds, aggds))
 
-    fn = shard_map(body, mesh=mesh, in_specs=PS(PART_AXIS),
+    fn = shard_map(_chain_stack, mesh=mesh, in_specs=PS(PART_AXIS),
                    out_specs=PS(PART_AXIS))
     return jax.jit(fn)
 
@@ -291,9 +312,11 @@ def _chain_fn(mesh: Mesh, tiers_struct: Tuple[int, ...], n_preds: int,
 def run_chain_stack(mesh: Mesh, tiers, bounds, idx_pad, n_live, preds,
                     aggds, total_p2: int, live_p2: int, n_parts: int):
     """Dispatch one stacked chain (plancache.run_all's device half).
-    Stacked pooled operands go through :func:`fetch_sharded`; bound
-    scalars stay dynamic [R] operands (excluded from h2d accounting by
-    the kernel convention).  Returns host (n_cand, n_found, n_valid,
+    Stacked pooled operands go through :func:`fetch_sharded` (span
+    ``chain.h2d``, as the loop's pool fetch); bound scalars stay dynamic
+    [R] operands (excluded from h2d accounting by the kernel
+    convention).  The core's call through its ``device_get`` is the
+    ``chain.device`` span.  Returns host (n_cand, n_found, n_valid,
     mask, per_col) arrays with a leading partition-row axis."""
     tiers_struct = tuple(len(fp) for fp in tiers)
     flat: List[np.ndarray] = []
@@ -304,7 +327,8 @@ def run_chain_stack(mesh: Mesh, tiers, bounds, idx_pad, n_live, preds,
         flat.extend((d, v))
     for d, v in aggds:
         flat.extend((d, v))
-    ops, missed = fetch_sharded(flat, mesh)
+    with obs.span("chain.h2d"):
+        ops, missed = fetch_sharded(flat, mesh)
     it = iter(ops)
     dev_tiers = tuple(tuple(next(it) for _ in fp) for fp in tiers)
     dev_idx = next(it)
@@ -313,13 +337,127 @@ def run_chain_stack(mesh: Mesh, tiers, bounds, idx_pad, n_live, preds,
     dev_aggs = tuple((next(it), next(it)) for _ in aggds)
     fn = _chain_fn(mesh, tiers_struct, len(preds), len(aggds),
                    total_p2, live_p2)
-    with jax.enable_x64(True):
+    with obs.span("chain.device"), jax.enable_x64(True):
         outs = fn(dev_tiers, bounds, dev_idx, n_live, dev_preds, dev_aggs)
         n_cand, n_found, n_valid, mask, per_col = jax.device_get(outs)
     mask_np = np.asarray(mask)
     _record_dispatch("spmd_index_chain", h2d=missed, d2h=[mask_np])
     _note_spmd(mesh, n_parts)
     return n_cand, n_found, n_valid, mask_np, per_col
+
+
+@functools.lru_cache(maxsize=128)
+def _tocc_fn(mesh: Mesh, tiers: Tuple[Tuple[int, int], ...], total_p2: int):
+    """jit(shard_map(T-occurrence)) for one stacked tier structure (per
+    tier slot its positions width and hits bucket).  A shard's partitions
+    share one flat count, ``total_p2`` cells a partition and one sentinel:
+    each partition's positions are raised by its row's base (``rowoff``,
+    the tier offset plus ``row * total_p2``), and the shard's segments of
+    a slot concatenate, so every slot is one 1-d gather and scatter-add
+    with the loop kernel's math (``fuzzy_ops._tocc_tier_math``) and no
+    batched scatter.  No collective: each chip counts its own
+    partitions."""
+    from ..kernels.fuzzy_ops import _tocc_tier_math
+    tr = _traces()
+
+    def _tocc_stack(pos, rowoff, starts, cum, nhits, thr):
+        tr["n"] += 1
+        _record_retrace()
+        rows = rowoff.shape[0]
+        cnt = jnp.zeros(rows * total_p2 + 1, dtype=jnp.int32)
+        for t, (_w, hits_p2) in enumerate(tiers):
+            flat = (pos[t] + rowoff[:, t:t + 1]).reshape(-1)
+            cnt = _tocc_tier_math(cnt, flat, starts[0, t], cum[0, t],
+                                  nhits[0, t], 0, hits_p2)
+        return cnt[:-1].reshape(rows, total_p2) >= thr[:, None]
+
+    fn = shard_map(_tocc_stack, mesh=mesh, in_specs=PS(PART_AXIS),
+                   out_specs=PS(PART_AXIS))
+    return jax.jit(fn)
+
+
+def batched_t_occurrence(parts: Sequence[Any]
+                         ) -> Optional[List[np.ndarray]]:
+    """All partitions' ngram T-occurrence in one shard_map dispatch over
+    the active mesh.  ``parts`` holds per partition either its finished
+    candidate mask or its ``(live selection, tiers, storage concat
+    length, T)`` operands (``storage/dataset._ngram_tocc_inputs``).
+    Returns every partition's candidate mask, bit for bit the loop's
+    ``ngram_t_occurrence(...)[live]``, or None (after a fallback count)
+    where fewer than two partitions have hits or the positions' dtypes
+    drift: the caller then runs the loop.  Tiers stack by slot (newest
+    first), each slot padded to its widest positions array through
+    :class:`StackCache`, so a warm query ships only segment bounds;
+    partitions with fewer tiers, or no hits, scatter nothing from the
+    empty slots.  Every partition that needs a count takes a row, hits or
+    not, so which stacks a query uses depends on the data alone."""
+    from ..columnar.batch import pow2_len
+    mesh = active_mesh()
+    if mesh is None:
+        return None
+    out: List[Optional[np.ndarray]] = [None] * len(parts)
+    entries = []               # (partition, idx, tiers, total, T, hits)
+    for i, x in enumerate(parts):
+        if isinstance(x, np.ndarray):
+            out[i] = x
+            continue
+        idx, tiers, total, thr = x
+        hits = [int((en - st).sum()) for _o, _p, _pp, st, en in tiers]
+        entries.append((i, idx, tiers, total, thr, hits))
+    n_hit = sum(1 for e in entries if sum(e[5]))
+    if not n_hit:
+        for i, idx, *_ in entries:
+            out[i] = np.zeros(len(idx), dtype=bool)
+        return out
+    dts = {str(t[2].dtype) for e in entries for t in e[2]}
+    if n_hit < 2 or len(dts) != 1:
+        _FALLBACKS.inc()
+        return None
+    with obs.span("fuzzy.tocc"):
+        n_slots = max(len(e[2]) for e in entries)
+        widths = [max(int(e[2][t][2].shape[0]) for e in entries
+                      if t < len(e[2])) for t in range(n_slots)]
+        gp = pow2_len(max(int(t[3].shape[0]) for e in entries
+                          for t in e[2]) or 1)
+        total_p2 = max(pow2_len(e[3]) for e in entries)
+        rows = rows_for(len(entries), mesh)
+        n_dev = int(mesh.devices.size)
+        local = rows // n_dev
+        dt = np.dtype(dts.pop())
+        pos = [stack_cache.stack([e[2][t][2] if t < len(e[2]) else None
+                                  for e in entries], rows, w, dt)
+               for t, w in enumerate(widths)]
+        # per shard and slot, its partitions' segments end to end: starts
+        # index the shard's flattened positions, lengths 0 pad each row
+        starts = np.zeros((n_dev, n_slots, local * gp), dtype=np.int64)
+        lens = np.zeros((n_dev, n_slots, local * gp), dtype=np.int64)
+        rowoff = np.zeros((rows, n_slots), dtype=np.int64)
+        thr = np.zeros(rows, dtype=np.int32)
+        for r, (_i, _idx, tiers, _total, t_thr, _hits) in enumerate(entries):
+            d, lr = divmod(r, local)
+            thr[r] = t_thr
+            for t, (off, _p, _pp, st, en) in enumerate(tiers):
+                lanes = slice(lr * gp, lr * gp + st.shape[0])
+                starts[d, t, lanes] = st + lr * widths[t]
+                lens[d, t, lanes] = en - st
+                rowoff[r, t] = off + lr * total_p2
+        cum = np.cumsum(lens, axis=2) - lens
+        nhits = lens.sum(axis=2)
+        slots = tuple((w, pow2_len(max(int(nhits[:, t].max()), 1)))
+                      for t, w in enumerate(widths))
+        ops, missed = fetch_sharded(pos, mesh)
+        fn = _tocc_fn(mesh, slots, total_p2)
+        with jax.enable_x64(True):
+            mask = np.asarray(jax.device_get(
+                fn(tuple(ops), rowoff, starts, cum, nhits, thr)))
+        _record_dispatch("spmd_t_occurrence", h2d=missed, d2h=[mask])
+        _note_spmd(mesh, len(entries))
+        _TOCC_DISPATCHES.inc()
+        _TOCC_HITS.inc(int(nhits.sum()))
+        _TOCC_CELLS.inc(len(entries) * total_p2)
+        for r, (i, idx, _tiers, total, _thr, _hits) in enumerate(entries):
+            out[i] = mask[r, :total][idx]
+    return out
 
 
 @functools.lru_cache(maxsize=128)

@@ -769,21 +769,16 @@ class PartitionedDataset:
             off += comp.size
         return sources, off
 
-    def ngram_candidate_mask(self, i: int, fld: str, spec: Tuple,
-                             _view: Optional[LSMView] = None
-                             ) -> np.ndarray:
-        """T-occurrence candidate bitmap over partition ``i``'s scan
-        positions (aligned with ``scan_partition_batch`` /
-        ``partition_pk_array``): the query grams' posting segments in
-        every storage tier feed one fused count kernel over the tiers'
-        device-resident positions, which keeps positions with >= T hits.
-        T <= 0 means the index cannot prune — every row with an indexable
-        value is a candidate."""
+    def _ngram_tocc_inputs(self, i: int, fld: str, spec: Tuple,
+                           view: LSMView) -> Any:
+        """Partition ``i``'s T-occurrence operands ``(live selection,
+        tiers, storage concat length, T)`` for ``ngram_t_occurrence``, or
+        the candidate mask itself where no count is needed (no live rows;
+        T <= 0, where the index cannot prune and every row with an
+        indexable value is a candidate)."""
         from ..fuzzy.ngram import query_grams
-        from ..kernels.fuzzy_ops import ngram_t_occurrence
         if fld not in self._ngram_specs:
             raise adm.ValidationError(f"no ngram index on {self.name}.{fld}")
-        view = _view if _view is not None else self._view(i)
         idx, _ = self._live_selection(i, view)
         if not len(idx):
             return np.zeros(0, dtype=bool)
@@ -796,7 +791,49 @@ class PartitionedDataset:
             return has[idx]
         tiers = [(off, p.positions, p.padded_positions())
                  + p.hit_segments(qh) for off, p in sources]
-        return ngram_t_occurrence(tiers, total, threshold)[idx]
+        return idx, tiers, total, threshold
+
+    @staticmethod
+    def _ngram_count(inputs: Any) -> np.ndarray:
+        """One partition's candidate mask from its ``_ngram_tocc_inputs``
+        (the per-partition loop's T-occurrence)."""
+        if isinstance(inputs, np.ndarray):
+            return inputs
+        from ..kernels.fuzzy_ops import ngram_t_occurrence
+        idx, tiers, total, threshold = inputs
+        with _obs.span("fuzzy.tocc"):
+            return ngram_t_occurrence(tiers, total, threshold)[idx]
+
+    def ngram_candidate_mask(self, i: int, fld: str, spec: Tuple,
+                             _view: Optional[LSMView] = None
+                             ) -> np.ndarray:
+        """T-occurrence candidate bitmap over partition ``i``'s scan
+        positions (aligned with ``scan_partition_batch`` /
+        ``partition_pk_array``): the query grams' posting segments in
+        every storage tier feed one fused count kernel over the tiers'
+        device-resident positions, which keeps positions with >= T hits.
+        T <= 0 means the index cannot prune — every row with an indexable
+        value is a candidate."""
+        view = _view if _view is not None else self._view(i)
+        return self._ngram_count(self._ngram_tocc_inputs(i, fld, spec, view))
+
+    def ngram_candidate_masks(self, fld: str, spec: Tuple,
+                              _views: Optional[Sequence[LSMView]] = None
+                              ) -> Optional[List[np.ndarray]]:
+        """Every partition's ``ngram_candidate_mask`` under an active
+        partition mesh: one stacked T-occurrence dispatch over the mesh
+        (``runtime/spmd.batched_t_occurrence``), or the per-partition loop
+        where the stacked path declines.  None without a mesh."""
+        from ..runtime import spmd
+        if spmd.active_mesh() is None:
+            return None
+        inputs = [self._ngram_tocc_inputs(
+            i, fld, spec, _views[i] if _views is not None else self._view(i))
+            for i in range(self.num_partitions)]
+        masks = spmd.batched_t_occurrence(inputs)
+        if masks is None:
+            masks = [self._ngram_count(x) for x in inputs]
+        return masks
 
     def ngram_search_partition(self, i: int, fld: str, spec: Tuple,
                                _view: Optional[LSMView] = None
@@ -1039,6 +1076,10 @@ class DatasetSnapshot:
                              ) -> np.ndarray:
         return self._ds.ngram_candidate_mask(i, fld, spec,
                                              _view=self._views[i])
+
+    def ngram_candidate_masks(self, fld: str, spec: Tuple
+                              ) -> Optional[List[np.ndarray]]:
+        return self._ds.ngram_candidate_masks(fld, spec, _views=self._views)
 
     def ngram_search_partition(self, i: int, fld: str, spec: Tuple
                                ) -> List[Tuple[Any, int]]:
